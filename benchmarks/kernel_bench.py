@@ -99,11 +99,10 @@ def _tiled_conv_report(full=False):
     print(" row-strip tiled conv: per-grid-cell VMEM working set "
           f"(budget {tiling.DEFAULT_VMEM_BUDGET >> 10} kB):")
     for name, hw, c_in, c_out, k, stride in geoms:
-        lo, hi, h_out = kref.same_pads(hw, k, stride)
-        wp = hw + lo + hi
+        _, _, h_out = kref.same_pads(hw, k, stride)
         bn, _ = ops._tile_pad(c_out, 128)  # the tile the kernel launches
-        weight_bytes = k * k * c_in * bn
-        kw = dict(k=k, stride=stride, h_out=h_out, w_out=h_out, wp=wp,
+        weight_bytes = tiling.vmem_bytes((k * k, c_in, bn), 1)
+        kw = dict(k=k, stride=stride, h_out=h_out, w_out=h_out,
                   c_in=c_in, bn=bn, weight_bytes=weight_bytes)
         tiled = tiling.plan_strips(**kw)
         whole = tiling.plan_strips(**kw, strip_h=h_out)

@@ -426,7 +426,11 @@ def ensure_compiled(params, mode: str, sparsity: float):
     (and unboxes) to its constant-parameter form; an already-compiled
     unboxed tree passes through UNTOUCHED — callers may rely on the
     identity (``out is params``) to share one host-side tree across
-    engines (serving/frontend.py does)."""
+    engines (serving/frontend.py does).  A serve mode the chip cannot
+    run is refused here, before any pruning or packing."""
+    if mode == "sparse_cfmm":
+        from repro.kernels import ops
+        ops._refuse_bitmap_on_tpu(ops._mode())
     boxed = any(isinstance(l, nn.Param) for l in jax.tree.leaves(
         params, is_leaf=lambda x: isinstance(x, nn.Param)))
     return nn.unbox(compile_params(params, mode=mode, sparsity=sparsity)) \

@@ -20,7 +20,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -90,8 +89,9 @@ def a2a_expert_exchange(x, expert_idx, gates, experts_apply, n_experts: int,
         out = out.at[tok].add(gathered * w)
         return out
 
-    fn = shard_map(local_fn, mesh=mesh,
-                   in_specs=(P((dp_axis, ep_axis)), P((dp_axis, ep_axis)),
-                             P((dp_axis, ep_axis))),
-                   out_specs=P((dp_axis, ep_axis)))
+    fn = jax.shard_map(local_fn, mesh=mesh,
+                       in_specs=(P((dp_axis, ep_axis)),
+                                 P((dp_axis, ep_axis)),
+                                 P((dp_axis, ep_axis))),
+                       out_specs=P((dp_axis, ep_axis)))
     return fn(x, expert_idx, gates)

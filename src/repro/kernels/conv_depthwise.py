@@ -11,14 +11,17 @@ elementwise tap-MAC:
 
 Weights arrive tap-major (k*k, C) int8 — stored that way at compile time
 (nn.dwconv_param already initializes in this layout, so compilation does
-zero shuffles) — and each grid cell holds a CHANNEL-TILED halo'd slab
-(slab_h, Wp, bn): unlike the dense kernel, whose every output tile needs
-all input channels, a depthwise output tile touches exactly its own bn
-input channels, so the slab read shrinks with the channel grid axis.
+zero shuffles) — and each grid cell holds a CHANNEL-TILED halo'd phase
+slab (P, strip_h + halo, w_out + halo, bn): unlike the dense kernel,
+whose every output tile needs all input channels, a depthwise output
+tile touches exactly its own bn input channels, so the slab read shrinks
+with the channel grid axis.
 
-Grid: (N, n_strips, C/bn).  Outputs match conv_implicit's contract —
-strip-blocked f32 y plus the per-(image, strip, tile) amax (and the
-optional zero-count pair) — so ops.conv2d_dw reuses the same unblocking
+Grid: (N, n_strips, C/bn).  Strided convs read the same stride² input
+phases as conv_implicit (``phase_split``), so every tap is a stride-1
+window.  Outputs match conv_implicit's contract — strip-blocked f32 y
+plus the per-(image, strip) column amax (and the optional zero-count
+pair) — so ops.conv2d_dw reuses the same unblocking
 and requantization tail as ops.conv2d.
 """
 from __future__ import annotations
@@ -29,124 +32,74 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.conv_implicit import collector_epilogue
-from repro.kernels.tiling import strip_geometry
+from repro.kernels.conv_implicit import launch_conv, out_hw
+from repro.kernels.tiling import strip_geometry, tap_phases
 
 
-def dw_tap_macs(x, w_tap, k, stride, h_out, w_out):
-    """Depthwise tap-MAC loop: one strided VMEM slice + VPU elementwise
-    multiply-accumulate per tap, the k*k loop unrolled at trace time.
+def dw_tap_macs(x_ref, w_tap, k, stride, h_out, w_out):
+    """Depthwise tap-MAC loop: one stride-1 VMEM window of the tap's
+    input phase + VPU elementwise multiply-accumulate per tap, the k*k
+    loop unrolled at trace time.
 
-    x: (slab_h, Wp, bn) int8 slab; w_tap: (k*k, bn) int8 -> (m_out, bn)
+    x_ref: (P, h_out + halo, w_out + halo, bn) int8 phase slab
+    (conv_implicit.phase_split); w_tap: (k*k, bn) int8 -> (m_out, bn)
     int32, m_out = h_out * w_out.
     """
-    bn = x.shape[-1]
+    bn = x_ref.shape[-1]
     m_out = h_out * w_out
+    phase = {p: i for i, p in enumerate(tap_phases(k, stride))}
     acc = jnp.zeros((m_out, bn), jnp.int32)
     for dy in range(k):
         for dx in range(k):
-            sl = jax.lax.slice(
-                x, (dy, dx, 0),
-                (dy + (h_out - 1) * stride + 1,
-                 dx + (w_out - 1) * stride + 1, bn),
-                (stride, stride, 1)).reshape(m_out, bn)
-            acc += sl.astype(jnp.int32) * w_tap[dy * k + dx].astype(jnp.int32)
+            p = phase[dy % stride, dx % stride]
+            # widened before the reshape: an int32 (h, w, bn) stack
+            # collapses for any w (see conv_implicit.window_rows)
+            sl = x_ref[p, pl.ds(dy // stride, h_out),
+                       pl.ds(dx // stride, w_out), :].astype(jnp.int32)
+            tap = dy * k + dx
+            acc += sl.reshape(m_out, bn) * w_tap[tap:tap + 1].astype(
+                jnp.int32)
     return acc
 
 
-def _kernel(*refs, k, stride, strip_h, h_out, w_out, ms_pad, relu,
-            has_shortcut, profile_g):
-    n_in = 5 if has_shortcut else 4
-    ins, outs = refs[:n_in], refs[n_in:]
-    if has_shortcut:
-        x_ref, w_ref, s_ref, b_ref, sc_ref = ins
-    else:
-        x_ref, w_ref, s_ref, b_ref = ins
-        sc_ref = None
-    out_ref, amax_ref = outs[0], outs[1]
-    zero_refs = (outs[2], outs[3]) if profile_g else None
-    x = x_ref[0]                            # (slab_h, Wp, bn) int8, VMEM
-    acc = dw_tap_macs(x, w_ref[...], k, stride, strip_h, w_out)
-    valid = jnp.minimum(strip_h, h_out - pl.program_id(1) * strip_h) * w_out
-    collector_epilogue(acc, s_ref, b_ref, sc_ref, out_ref, amax_ref,
-                       m_out=strip_h * w_out, m_pad=ms_pad, relu=relu,
-                       valid_rows=valid, zero_refs=zero_refs,
-                       group_size=profile_g)
-
-
 @functools.partial(jax.jit, static_argnames=(
-    "k", "stride", "h_out", "w_out", "bn", "strip_h", "relu", "interpret",
-    "profile_g"))
-def conv2d_dw_pallas(x_pad: jax.Array, w_tap: jax.Array,
+    "k", "stride", "bn", "strip_h", "relu", "interpret", "profile_g"))
+def conv2d_dw_pallas(x: jax.Array, w_tap: jax.Array,
                      eff_scale: jax.Array, eff_bias: jax.Array,
                      shortcut: jax.Array | None = None, *,
-                     k: int, stride: int, h_out: int, w_out: int,
-                     bn: int = 128, strip_h: int | None = None,
+                     k: int, stride: int, bn: int = 128,
+                     strip_h: int | None = None,
                      relu: bool = True, interpret: bool = False,
                      profile_g: int | None = None):
     """Fused row-strip-tiled depthwise conv.
 
-    x_pad:     (N, Hp, Wp, C) int8, SAME-padded and bottom-padded to the
-               strip plan's x_rows (channels padded to the bn tile)
+    x:         (N, H, W, C) int8, unpadded, channels padded to the bn
+               tile; the launch SAME-pads and phase-splits it
     w_tap:     (k*k, C) int8, tap-major (the compile-time storage layout)
     eff_scale: (N, C) f32 = s_x[row] * w_scale[channel] * bn_scale
     eff_bias:  (1, C) f32
     shortcut:  optional (N, n_strips*ms_pad, C) f32, strip-blocked
     Returns (y, amax) — strip-blocked f32 y (N, n_strips*ms_pad, C) and
-    per-(image, strip, channel-tile) max|y| over valid rows — or
+    per-(image, strip, channel) max|y| over valid rows — or
     (y, amax, zg, za) with ``profile_g`` (same contract as the dense
     implicit-GEMM kernel, shared unblocking in ops.conv2d_dw).
     """
-    N, Hp, Wp, C = x_pad.shape
+    N, _, _, C = x.shape
     KK, n_out = w_tap.shape
     assert KK == k * k and n_out == C and C % bn == 0, \
         ((KK, k), (n_out, C, bn))
     assert eff_scale.shape == (N, C), (eff_scale.shape, N, C)
+    h_out, w_out = out_hw(x, stride)
     g = strip_geometry(k=k, stride=stride, h_out=h_out, w_out=w_out,
                        strip_h=strip_h if strip_h is not None else h_out)
-    assert Hp >= g.x_rows and Wp >= (w_out - 1) * stride + k, \
-        ((Hp, Wp), g.x_rows)
-    n_j = C // bn
-    kern = functools.partial(_kernel, k=k, stride=stride, strip_h=g.strip_h,
-                             h_out=h_out, w_out=w_out, ms_pad=g.ms_pad,
-                             relu=relu, has_shortcut=shortcut is not None,
-                             profile_g=profile_g)
-    in_specs = [
-        # overlapping halo'd slabs, channel-tiled: a depthwise output tile
-        # reads only its own bn input channels (Unblocked element offsets)
-        pl.BlockSpec((1, g.slab_h, Wp, bn),
-                     lambda n, s, j: (n, s * g.row_step, 0, j * bn),
-                     indexing_mode=pl.unblocked),
-        pl.BlockSpec((KK, bn), lambda n, s, j: (0, j)),
-        # eff_scale: one dequant row PER IMAGE (per-row quant domains)
-        pl.BlockSpec((1, bn), lambda n, s, j: (n, j)),
-        pl.BlockSpec((1, bn), lambda n, s, j: (0, j)),
-    ]
-    args = [x_pad, w_tap, eff_scale, eff_bias]
-    if shortcut is not None:
-        assert shortcut.shape == (N, g.n_strips * g.ms_pad, C), \
-            (shortcut.shape, g)
-        in_specs.append(
-            pl.BlockSpec((1, g.ms_pad, bn), lambda n, s, j: (n, s, j)))
-        args.append(shortcut.astype(jnp.float32))
-    out_specs = [pl.BlockSpec((1, g.ms_pad, bn), lambda n, s, j: (n, s, j)),
-                 pl.BlockSpec((1, 1, 1), lambda n, s, j: (n, s, j))]
-    out_shape = [jax.ShapeDtypeStruct((N, g.n_strips * g.ms_pad, C),
-                                      jnp.float32),
-                 jax.ShapeDtypeStruct((N, g.n_strips, n_j), jnp.float32)]
-    if profile_g:
-        assert bn % profile_g == 0, (bn, profile_g)
-        gpb = bn // profile_g
-        out_specs += [pl.BlockSpec((1, 1, 1, gpb),
-                                   lambda n, s, j: (n, s, j, 0))] * 2
-        out_shape += [jax.ShapeDtypeStruct((N, g.n_strips, n_j, gpb),
-                                           jnp.float32)] * 2
-    outs = pl.pallas_call(
-        kern,
-        grid=(N, g.n_strips, n_j),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*args)
-    return tuple(outs)
+
+    def macs(x_ref, w_ref):
+        return dw_tap_macs(x_ref, w_ref[...], k, stride, g.strip_h, w_out)
+
+    # channel-tiled: a depthwise output tile reads only its own bn input
+    # channels
+    weights = [(w_tap, pl.BlockSpec((KK, bn), lambda n, s, j: (0, j)))]
+    return launch_conv(macs, x, weights, eff_scale, eff_bias, shortcut,
+                       k=k, stride=stride, h_out=h_out, g=g, bn=bn,
+                       relu=relu, profile_g=profile_g, interpret=interpret,
+                       c_block=bn)

@@ -41,64 +41,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.bitmap import expand_bitmap_tile
-from repro.kernels.conv_implicit import collector_epilogue, conv_tap_macs
+from repro.kernels.conv_implicit import conv_tap_macs, launch_conv, out_hw
 from repro.kernels.tiling import strip_geometry
 
 
-def _kernel(*refs, k, stride, strip_h, h_out, w_out, ms_pad, relu,
-            has_shortcut, c_in, keep_k, profile_g):
-    n_in = 6 if has_shortcut else 5
-    ins, outs = refs[:n_in], refs[n_in:]
-    if has_shortcut:
-        x_ref, bm_ref, val_ref, s_ref, b_ref, sc_ref = ins
-    else:
-        x_ref, bm_ref, val_ref, s_ref, b_ref = ins
-        sc_ref = None
-    out_ref, amax_ref = outs[0], outs[1]
-    zero_refs = (outs[2], outs[3]) if profile_g else None
-    x = x_ref[0]                                # (slab_h, Wp, C) int8, VMEM
-    C = x.shape[-1]
-    bn = out_ref.shape[2]
-    vals = val_ref[...]
-    # the MAC loop and Collector are conv_implicit's own (shared code, so
-    # sparse == dense bit-identity holds by construction); only the tap
-    # weight sourcing differs — packed bytes expand on the fly in VMEM
-    if c_in % 8 == 0:                              # tap rows byte-aligned:
-        def tap_weights(tap, base):                # expand fused per tap,
-            bm8 = bm_ref[tap * C // 8:(tap + 1) * C // 8, :]
-            return expand_bitmap_tile(bm8, vals, base, keep_k)
-        carry = jnp.zeros((1, bn), jnp.int32)      # running nonzero count
-    else:                                          # taps straddle bytes
-        w_dense, _ = expand_bitmap_tile(           # (stem): one-shot slab
-            bm_ref[...], vals, jnp.zeros((1, bn), jnp.int32), keep_k)
-
-        def tap_weights(tap, carry):
-            return jax.lax.slice(w_dense, (tap * C, 0),
-                                 ((tap + 1) * C, bn)), carry
-        carry = None
-    acc = conv_tap_macs(x, k, stride, strip_h, w_out, bn, tap_weights, carry)
-    valid = jnp.minimum(strip_h, h_out - pl.program_id(1) * strip_h) * w_out
-    collector_epilogue(acc, s_ref, b_ref, sc_ref, out_ref, amax_ref,
-                       m_out=strip_h * w_out, m_pad=ms_pad, relu=relu,
-                       valid_rows=valid, zero_refs=zero_refs,
-                       group_size=profile_g)
-
-
 @functools.partial(jax.jit, static_argnames=(
-    "k", "stride", "h_out", "w_out", "bn", "strip_h", "relu", "interpret",
-    "profile_g"))
-def conv2d_sparse_pallas(x_pad: jax.Array, bitmap: jax.Array,
+    "k", "stride", "bn", "strip_h", "relu", "interpret", "profile_g"))
+def conv2d_sparse_pallas(x: jax.Array, bitmap: jax.Array,
                          values: jax.Array, eff_scale: jax.Array,
                          eff_bias: jax.Array,
                          shortcut: jax.Array | None = None, *,
-                         k: int, stride: int, h_out: int, w_out: int,
-                         bn: int = 128, strip_h: int | None = None,
+                         k: int, stride: int, bn: int = 128,
+                         strip_h: int | None = None,
                          relu: bool = True, interpret: bool = False,
                          profile_g: int | None = None):
     """Fused bitmap-native row-strip-tiled implicit-GEMM sparse conv.
 
-    x_pad:     (N, Hp, Wp, C) int8, SAME-padded (ref.pad_same_nhwc) and
-               bottom-padded with zero rows to the strip plan's x_rows
+    x:         (N, H, W, C) int8, unpadded; the launch SAME-pads and
+               phase-splits it
     bitmap:    (K_pad/8, n_out) uint8, spatial-major taps, K_pad =
                k*k*C rounded up to a multiple of 8 (zero-masked tail)
     values:    (keep_k, n_out) int8 nonzero codes, ascending-row order
@@ -112,57 +72,40 @@ def conv2d_sparse_pallas(x_pad: jax.Array, bitmap: jax.Array,
     Returns (y, amax) exactly as conv2d_implicit_pallas
     ((y, amax, zg, za) with ``profile_g``).
     """
-    N, Hp, Wp, C = x_pad.shape
+    N, _, _, C = x.shape
     Kb8, n_out = bitmap.shape
     keep_k = values.shape[0]
     assert Kb8 * 8 == -(-k * k * C // 8) * 8, (Kb8, k, C)
     assert n_out % bn == 0 and values.shape[1] == n_out, (n_out, bn)
     assert eff_scale.shape == (N, n_out), (eff_scale.shape, N, n_out)
+    h_out, w_out = out_hw(x, stride)
     g = strip_geometry(k=k, stride=stride, h_out=h_out, w_out=w_out,
                        strip_h=strip_h if strip_h is not None else h_out)
-    assert Hp >= g.x_rows and Wp >= (w_out - 1) * stride + k, \
-        ((Hp, Wp), g.x_rows)
-    n_j = n_out // bn
-    kern = functools.partial(_kernel, k=k, stride=stride, strip_h=g.strip_h,
-                             h_out=h_out, w_out=w_out, ms_pad=g.ms_pad,
-                             relu=relu, has_shortcut=shortcut is not None,
-                             c_in=C, keep_k=keep_k, profile_g=profile_g)
-    in_specs = [
-        # overlapping halo'd slabs: Unblocked = element-offset indexing
-        pl.BlockSpec((1, g.slab_h, Wp, C),
-                     lambda n, s, j: (n, s * g.row_step, 0, 0),
-                     indexing_mode=pl.unblocked),
-        pl.BlockSpec((Kb8, bn), lambda n, s, j: (0, j)),
-        pl.BlockSpec((keep_k, bn), lambda n, s, j: (0, j)),
-        # eff_scale: one dequant row PER IMAGE (per-row quant domains)
-        pl.BlockSpec((1, bn), lambda n, s, j: (n, j)),
-        pl.BlockSpec((1, bn), lambda n, s, j: (0, j)),
-    ]
-    args = [x_pad, bitmap, values, eff_scale, eff_bias]
-    if shortcut is not None:
-        assert shortcut.shape == (N, g.n_strips * g.ms_pad, n_out), \
-            (shortcut.shape, g)
-        in_specs.append(
-            pl.BlockSpec((1, g.ms_pad, bn), lambda n, s, j: (n, s, j)))
-        args.append(shortcut.astype(jnp.float32))
-    out_specs = [pl.BlockSpec((1, g.ms_pad, bn), lambda n, s, j: (n, s, j)),
-                 pl.BlockSpec((1, 1, 1), lambda n, s, j: (n, s, j))]
-    out_shape = [jax.ShapeDtypeStruct((N, g.n_strips * g.ms_pad, n_out),
-                                      jnp.float32),
-                 jax.ShapeDtypeStruct((N, g.n_strips, n_j), jnp.float32)]
-    if profile_g:
-        assert bn % profile_g == 0, (bn, profile_g)
-        gpb = bn // profile_g
-        out_specs += [pl.BlockSpec((1, 1, 1, gpb),
-                                   lambda n, s, j: (n, s, j, 0))] * 2
-        out_shape += [jax.ShapeDtypeStruct((N, g.n_strips, n_j, gpb),
-                                           jnp.float32)] * 2
-    outs = pl.pallas_call(
-        kern,
-        grid=(N, g.n_strips, n_j),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*args)
-    return tuple(outs)
+
+    def macs(x_ref, bm_ref, val_ref):
+        # the MAC loop and Collector are conv_implicit's own (shared
+        # code, so sparse == dense bit-identity holds by construction);
+        # only the tap weight sourcing differs — packed bytes expand on
+        # the fly in VMEM
+        vals = val_ref[...]
+        if C % 8 == 0:                             # tap rows byte-aligned:
+            def tap_weights(tap, base):            # expand fused per tap,
+                bm8 = bm_ref[tap * C // 8:(tap + 1) * C // 8, :]
+                return expand_bitmap_tile(bm8, vals, base, keep_k)
+            carry = jnp.zeros((1, bn), jnp.int32)  # running nonzero count
+        else:                                      # taps straddle bytes
+            w_dense, _ = expand_bitmap_tile(       # (stem): one-shot slab
+                bm_ref[...], vals, jnp.zeros((1, bn), jnp.int32), keep_k)
+
+            def tap_weights(tap, carry):
+                return jax.lax.slice(w_dense, (tap * C, 0),
+                                     ((tap + 1) * C, bn)), carry
+            carry = None
+        return conv_tap_macs(x_ref, k, stride, g.strip_h, w_out, bn,
+                             tap_weights, carry)
+
+    weights = [(bitmap, pl.BlockSpec((Kb8, bn), lambda n, s, j: (0, j))),
+               (values, pl.BlockSpec((keep_k, bn), lambda n, s, j: (0, j)))]
+    return launch_conv(macs, x, weights, eff_scale, eff_bias, shortcut,
+                       k=k, stride=stride, h_out=h_out, g=g, bn=bn,
+                       relu=relu, profile_g=profile_g, interpret=interpret)

@@ -1,10 +1,10 @@
 """Backend dispatch for the Pallas kernels.
 
-On TPU the Pallas kernels run natively; everywhere else (this CPU
-container, and the multi-pod dry-run) the mathematically identical jnp
-references lower instead — same dtypes, same sharding, so compiled HLO
-stays representative.  Set REPRO_PALLAS=interpret to force the kernels
-through Pallas interpret mode (used by the kernel test-suite).
+``REPRO_PALLAS`` picks the lowering: ``tpu`` (the Pallas kernels,
+compiled by Mosaic), ``interpret`` (the same kernels through the Pallas
+interpreter — the kernel test-suite on CPU), ``jnp`` (the
+mathematically identical jnp references), or ``auto`` / unset: ``tpu``
+on a TPU backend, ``jnp`` everywhere else.  Any other value raises.
 """
 from __future__ import annotations
 
@@ -17,11 +17,32 @@ import numpy as np
 from repro.kernels import ref, tiling
 
 
+MODES = ("auto", "tpu", "interpret", "jnp")
+
+
+class UnsupportedOnTPU(NotImplementedError):
+    """A serve mode whose kernel Mosaic cannot compile yet.  Raised
+    instead of silently lowering that op to a jnp reference on the
+    chip."""
+
+
 def _mode() -> str:
     env = os.environ.get("REPRO_PALLAS", "auto")
-    if env in ("interpret", "jnp", "tpu"):
+    if env not in MODES:
+        raise ValueError(f"REPRO_PALLAS={env!r}: expected one of {MODES}")
+    if env != "auto":
         return env
     return "tpu" if jax.default_backend() == "tpu" else "jnp"
+
+
+def _refuse_bitmap_on_tpu(mode: str):
+    """The bitmap expand (kernels/bitmap.py) needs a cumsum and a per-lane
+    gather, which Mosaic does not lower: bitmap-packed weights cannot be
+    served on the chip yet, and must not quietly take another path."""
+    if mode == "tpu":
+        raise UnsupportedOnTPU(
+            "sparse_cfmm: the on-chip bitmap expand does not compile with "
+            "Mosaic yet — serve int8 or cfmm on a TPU")
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int):
@@ -73,19 +94,32 @@ def _largest_tile(dim: int, cap: int) -> int:
 
 def _tile_pad(dim: int, cap: int) -> tuple[int, int]:
     """(tile, padded_dim) for a lane-tiled axis: one tile when the axis
-    fits the cap, else the largest clean divisor.  When an awkward axis
-    would degrade toward one grid cell per element — the old
-    ``_largest_tile`` pathology: a prime gives tile 1, and 8*prime a
-    sliver tile of 8 — pad the axis to the next cap multiple instead and
-    let the caller slice the result; zero pad rows/columns are exact
-    under int8 matmul.  A divisor tile is kept only when it is both a
-    sublane multiple and a reasonable fraction (>= 1/4) of the cap."""
+    fits the cap, else the largest divisor that is a whole number of
+    128-lane vregs — Mosaic takes a lane block only as a 128 multiple or
+    the whole axis.  An axis with no such divisor (a prime, 8*prime, or
+    192 under a 128 cap) is padded to the next cap multiple and the
+    caller slices the result; zero pad rows/columns are exact under int8
+    matmul.  ``cap`` is a multiple of 128."""
     if dim <= cap:
         return dim, dim
-    t = _largest_tile(dim, cap)
-    if t % 8 == 0 and t >= cap // 4:
-        return t, dim
+    for t in range(cap, 0, -128):
+        if dim % t == 0:
+            return t, dim
     return cap, -(-dim // cap) * cap
+
+
+def _conv_lane_tile(n: int, plan_for) -> tuple[int, int]:
+    """(bn, n_pad) for a conv's channel grid axis.  ``_tile_pad``'s
+    128-lane tile where the axis has one; an axis without (mobilenet_v2's
+    144, 576, 960) is one whole-axis block — the other lane block Mosaic
+    takes — unless that cell overruns the VMEM budget even at one-row
+    strips, and only then padded to 128-lane tiles.  ``plan_for(bn)`` is
+    the caller's strip plan for a channel tile of ``bn``."""
+    bn, n_pad = _tile_pad(n, 128)
+    if (n_pad > n and plan_for(n).cell_bytes
+            <= tiling.DEFAULT_VMEM_BUDGET):
+        return n, n
+    return bn, n_pad
 
 
 def sparse_cfmm_matmul(x_q: jax.Array, bitmap: jax.Array,
@@ -93,6 +127,7 @@ def sparse_cfmm_matmul(x_q: jax.Array, bitmap: jax.Array,
                        scale: jax.Array | None = None) -> jax.Array:
     """Bitmap-packed sparse matmul; int32 out (or f32 with scale fused)."""
     mode = _mode()
+    _refuse_bitmap_on_tpu(mode)
     if bitmap.shape[0] * 8 != x_q.shape[1]:
         # K padded to a multiple of 8 at compile time (masked tail rows);
         # zero int8 activations are exact, so pad x to match
@@ -226,6 +261,7 @@ def conv2d(x_q: jax.Array, codes: jax.Array, k: int, stride: int, *,
     N, H, W, C = x_q.shape
     packed = isinstance(codes, (tuple, list))
     if packed:
+        _refuse_bitmap_on_tpu(mode)
         bitmap, values = codes
         n_out = bitmap.shape[1]
         assert bitmap.shape[0] * 8 == -(-C * k * k // 8) * 8, (
@@ -262,9 +298,26 @@ def conv2d(x_q: jax.Array, codes: jax.Array, k: int, stride: int, *,
         amax_of = (lambda: jnp.max(jnp.abs(y), axis=(1, 2, 3))) if per_row \
             else (lambda: jnp.max(jnp.abs(y)))
     else:
-        xp, h_out, w_out = ref.pad_same_nhwc(x_q, k, stride)
+        _, _, h_out = ref.same_pads(H, k, stride)
+        _, _, w_out = ref.same_pads(W, k, stride)
         m_out = h_out * w_out
-        bn, n_pad = _tile_pad(n_out, 128)
+
+        def plan_for(bn, strip_h=None):
+            if packed:             # per-cell weight slab for the planner:
+                weight_bytes = (tiling.vmem_bytes((bitmap.shape[0], bn), 1)
+                                + tiling.vmem_bytes((values.shape[0], bn),
+                                                    1))
+                if C % 8 != 0:     # + the one-shot expanded slab (stem)
+                    weight_bytes += tiling.vmem_bytes(
+                        (bitmap.shape[0] * 8, bn), 1)
+            else:
+                weight_bytes = tiling.vmem_bytes((k * k, C, bn), 1)
+            return tiling.plan_strips(
+                k=k, stride=stride, h_out=h_out, w_out=w_out, c_in=C,
+                bn=bn, weight_bytes=weight_bytes,
+                has_shortcut=shortcut is not None, strip_h=strip_h)
+
+        bn, n_pad = _conv_lane_tile(n_out, plan_for)
         if n_pad > n_out:          # awkward channel count: zero-pad + slice
             if packed:
                 bitmap = jnp.pad(bitmap, ((0, 0), (0, n_pad - n_out)))
@@ -273,20 +326,7 @@ def conv2d(x_q: jax.Array, codes: jax.Array, k: int, stride: int, *,
                 codes = jnp.pad(codes, ((0, 0), (0, n_pad - n_out)))
             eff_scale = jnp.pad(eff_scale, ((0, 0), (0, n_pad - n_out)))
             eff_bias = jnp.pad(eff_bias, (0, n_pad - n_out))
-        if packed:                 # per-cell weight slab for the planner:
-            weight_bytes = (bitmap.shape[0] + values.shape[0]) * bn
-            if C % 8 != 0:         # + the one-shot expanded slab (stem)
-                weight_bytes += bitmap.shape[0] * 8 * bn
-        else:
-            weight_bytes = k * k * C * bn
-        plan = tiling.plan_strips(k=k, stride=stride, h_out=h_out,
-                                  w_out=w_out, wp=xp.shape[2], c_in=C,
-                                  bn=bn, weight_bytes=weight_bytes,
-                                  has_shortcut=shortcut is not None,
-                                  strip_h=strip_h)
-        if xp.shape[1] < plan.x_rows:  # zero rows for the last strip's slab
-            xp = jnp.pad(xp, ((0, 0), (0, plan.x_rows - xp.shape[1]),
-                              (0, 0), (0, 0)))
+        plan = plan_for(bn, strip_h)
         sc = None
         if shortcut is not None:
             sc = _strip_blocked(
@@ -300,9 +340,8 @@ def conv2d(x_q: jax.Array, codes: jax.Array, k: int, stride: int, *,
         profile_fast = (zero_count is not None and n_pad == n_out
                         and n_out % zero_count == 0
                         and bn % zero_count == 0)
-        kw = dict(k=k, stride=stride, h_out=h_out, w_out=w_out, bn=bn,
-                  strip_h=plan.strip_h, relu=relu,
-                  interpret=(mode == "interpret"),
+        kw = dict(k=k, stride=stride, bn=bn, strip_h=plan.strip_h,
+                  relu=relu, interpret=(mode == "interpret"),
                   profile_g=zero_count if profile_fast else None)
         # the kernels index eff_scale per image (grid axis n) so per-row
         # domains ride the same launch; a per-tensor scalar broadcasts
@@ -310,28 +349,29 @@ def conv2d(x_q: jax.Array, codes: jax.Array, k: int, stride: int, *,
         if packed:
             from repro.kernels.conv_sparse import conv2d_sparse_pallas
             outs = conv2d_sparse_pallas(
-                xp, bitmap, values, eff_rows,
+                x_q, bitmap, values, eff_rows,
                 eff_bias.reshape(1, n_pad), sc, **kw)
         else:
             from repro.kernels.conv_implicit import conv2d_implicit_pallas
             if w_layout == "channel":  # pre-compile codes pay the permute
                 codes = ref.to_spatial_major(codes, k, C)
             outs = conv2d_implicit_pallas(
-                xp, codes, eff_rows,
+                x_q, codes, eff_rows,
                 eff_bias.reshape(1, n_pad), sc, **kw)
         y_flat, _amax = outs[0], outs[1]
         y = y_flat.reshape(N, plan.n_strips, plan.ms_pad, n_pad)[
             :, :, :plan.ms, :n_out]
         y = y.reshape(N, plan.n_strips * plan.ms, n_out)[:, :m_out]
         y = y.reshape(N, h_out, w_out, n_out)
-        # reduced on-chip in the epilogue: (N, n_strips, n_j) -> whole-
-        # tensor max, or max over strips/tiles only (keep N) per-row
-        amax_of = (lambda: jnp.max(_amax, axis=(1, 2))) if per_row \
+        # reduced on-chip in the epilogue: (N, n_strips, 1, n_pad) ->
+        # whole-tensor max, or max over strips/channels only (keep N)
+        # per-row
+        amax_of = (lambda: jnp.max(_amax, axis=(1, 2, 3))) if per_row \
             else (lambda: jnp.max(_amax))
     zc = None
     if zero_count is not None:
         if profile_fast:
-            # kernel outputs: (N, n_strips, n_j, groups/tile) valid-row
+            # kernel outputs: (N, n_strips, n_j, 1, groups/tile) valid-row
             # zero counts; flatten (tile, in-tile group) -> the global
             # channel-group axis and reduce on the right axes
             m_out = y.shape[1] * y.shape[2]
@@ -399,26 +439,28 @@ def conv2d_dw(x_q: jax.Array, values: jax.Array, k: int, stride: int, *,
         amax_of = (lambda: jnp.max(jnp.abs(y), axis=(1, 2, 3))) if per_row \
             else (lambda: jnp.max(jnp.abs(y)))
     else:
-        xp, h_out, w_out = ref.pad_same_nhwc(x_q, k, stride)
+        _, _, h_out = ref.same_pads(H, k, stride)
+        _, _, w_out = ref.same_pads(W, k, stride)
         m_out = h_out * w_out
-        bn, n_pad = _tile_pad(C, 128)
+
+        def plan_for(bn, strip_h=None):
+            # the slab is channel-tiled (bn channels per cell), so the
+            # planner's activation term scales with bn, not C
+            return tiling.plan_strips(
+                k=k, stride=stride, h_out=h_out, w_out=w_out, c_in=bn,
+                bn=bn, weight_bytes=tiling.vmem_bytes((k * k, bn), 1),
+                has_shortcut=shortcut is not None, strip_h=strip_h)
+
+        bn, n_pad = _conv_lane_tile(C, plan_for)
+        x_c = x_q
         if n_pad > C:              # awkward channel count: zero-pad + slice
             # zero input channels x zero weight channels -> zero outputs,
             # exact under int8 MACs; the pad is sliced off below
-            xp = jnp.pad(xp, ((0, 0), (0, 0), (0, 0), (0, n_pad - C)))
+            x_c = jnp.pad(x_q, ((0, 0), (0, 0), (0, 0), (0, n_pad - C)))
             values = jnp.pad(values, ((0, 0), (0, n_pad - C)))
             eff_scale = jnp.pad(eff_scale, ((0, 0), (0, n_pad - C)))
             eff_bias = jnp.pad(eff_bias, (0, n_pad - C))
-        # the slab is channel-tiled (bn channels per cell), so the
-        # planner's activation term scales with bn, not C
-        plan = tiling.plan_strips(k=k, stride=stride, h_out=h_out,
-                                  w_out=w_out, wp=xp.shape[2], c_in=bn,
-                                  bn=bn, weight_bytes=k * k * bn,
-                                  has_shortcut=shortcut is not None,
-                                  strip_h=strip_h)
-        if xp.shape[1] < plan.x_rows:  # zero rows for the last strip's slab
-            xp = jnp.pad(xp, ((0, 0), (0, plan.x_rows - xp.shape[1]),
-                              (0, 0), (0, 0)))
+        plan = plan_for(bn, strip_h)
         sc = None
         if shortcut is not None:
             sc = _strip_blocked(
@@ -430,9 +472,8 @@ def conv2d_dw(x_q: jax.Array, values: jax.Array, k: int, stride: int, *,
         eff_rows = jnp.broadcast_to(eff_scale, (N, n_pad))
         from repro.kernels.conv_depthwise import conv2d_dw_pallas
         outs = conv2d_dw_pallas(
-            xp, values, eff_rows, eff_bias.reshape(1, n_pad), sc,
-            k=k, stride=stride, h_out=h_out, w_out=w_out, bn=bn,
-            strip_h=plan.strip_h, relu=relu,
+            x_c, values, eff_rows, eff_bias.reshape(1, n_pad), sc,
+            k=k, stride=stride, bn=bn, strip_h=plan.strip_h, relu=relu,
             interpret=(mode == "interpret"),
             profile_g=zero_count if profile_fast else None)
         y_flat, _amax = outs[0], outs[1]
@@ -440,7 +481,7 @@ def conv2d_dw(x_q: jax.Array, values: jax.Array, k: int, stride: int, *,
             :, :, :plan.ms, :C]
         y = y.reshape(N, plan.n_strips * plan.ms, C)[:, :m_out]
         y = y.reshape(N, h_out, w_out, C)
-        amax_of = (lambda: jnp.max(_amax, axis=(1, 2))) if per_row \
+        amax_of = (lambda: jnp.max(_amax, axis=(1, 2, 3))) if per_row \
             else (lambda: jnp.max(_amax))
     zc = None
     if zero_count is not None:

@@ -23,33 +23,94 @@ after the launch.
 ``plan_strips`` picks the largest ``strip_h`` whose working set fits a
 VMEM budget; 7×7-map layers (conv5_x) degenerate to a single strip, i.e.
 exactly the pre-tiling kernel.
+
+On the chip a strided conv reads its input as stride² *phases*
+(``tap_phases``): phase (ry, rx) holds padded pixels (i·s+ry, j·s+rx),
+so every tap becomes a stride-1 window of one phase and a strip's slab
+is ``strip_h + halo`` phase rows, ``halo = (k-1)//stride``.
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 
-# Per-grid-cell working-set budget.  VMEM is ~16 MB/core; 1 MiB per cell
-# leaves room for double-buffered input/weight streams and keeps several
-# (n, strip, c_out-tile) cells in flight.  The 224×224 k=7 stem's
-# whole-image working set (dominated by the 112×112-row accumulator)
-# shrinks well over 4× under it (tracked in BENCH_conv.json).
-DEFAULT_VMEM_BUDGET = 1 << 20
+# Per-grid-cell VMEM budget, counted as Mosaic lays the blocks out (see
+# ``vmem_bytes``): inputs and outputs double-buffered, plus the kernel's
+# accumulator-sized temporaries.  v5e's default scoped VMEM limit is
+# 16 MiB; 6 MiB leaves the compiler room for its own scratch.
+DEFAULT_VMEM_BUDGET = 6 << 20
+
+
+def vmem_bytes(shape, itemsize: int) -> int:
+    """Bytes one VMEM block of ``shape`` occupies on the chip: the last
+    two dims pad to whole (32/itemsize, 128) tiles — (32, 128) for int8,
+    (8, 128) for f32/int32."""
+    *lead, r, c = (1,) * (2 - len(shape)) + tuple(shape)
+    sub = 32 // itemsize
+    return (math.prod(lead) * -(-r // sub) * sub * -(-c // 128) * 128
+            * itemsize)
+
+
+def tap_phases(k: int, stride: int) -> tuple:
+    """The (row, col) phases of a stride-``stride`` input that the k×k taps
+    read, in the order the kernels stack them: tap (dy, dx) reads phase
+    (dy % stride, dx % stride) at phase offset (dy // stride, dx //
+    stride).  All stride² phases when k >= stride; only (0, 0) for a
+    strided 1×1."""
+    return tuple(sorted({(dy % stride, dx % stride)
+                         for dy in range(k) for dx in range(k)}))
 
 
 @dataclasses.dataclass(frozen=True)
 class StripPlan:
     """Static row-strip geometry (plus working-set accounting) for one
-    conv launch."""
+    conv launch.  The kernels read the phase geometry (``halo``,
+    ``ph_rows``); the raw padded-input view of the same strips
+    (``slab_h``, ``row_step``, ``x_rows``) is derived for the strip-looped
+    jnp oracles."""
 
+    k: int
+    stride: int
+    w_out: int
     strip_h: int     # output rows per strip
     n_strips: int    # ceil(h_out / strip_h)
-    slab_h: int      # input rows resident per cell = (strip_h-1)*stride + k
-    row_step: int    # input-row stride between strips = strip_h * stride
-    ms: int          # output elements per strip = strip_h * w_out
-    ms_pad: int      # ms rounded up to the f32 sublane multiple (8)
-    x_rows: int      # padded-input rows the kernel reads overall
-    x_bytes: int = 0     # int8 activation slab bytes per cell
-    cell_bytes: int = 0  # slab + weight tile + acc/y (+shortcut) bytes
+    x_bytes: int = 0     # VMEM bytes of the double-buffered x slab
+    cell_bytes: int = 0  # VMEM bytes of every block + temporaries per cell
+
+    @property
+    def halo(self) -> int:
+        """Extra phase rows/cols a strip reads: (k-1)//stride."""
+        return (self.k - 1) // self.stride
+
+    @property
+    def ph_rows(self) -> int:
+        """Rows of each input phase the kernels read overall."""
+        return self.n_strips * self.strip_h + self.halo
+
+    @property
+    def ms(self) -> int:
+        """Output elements per strip."""
+        return self.strip_h * self.w_out
+
+    @property
+    def ms_pad(self) -> int:
+        """``ms`` rounded up to the f32 sublane multiple (8)."""
+        return -(-self.ms // 8) * 8
+
+    @property
+    def slab_h(self) -> int:
+        """Padded-input rows one strip's taps read."""
+        return (self.strip_h - 1) * self.stride + self.k
+
+    @property
+    def row_step(self) -> int:
+        """Padded-input rows between consecutive strips."""
+        return self.strip_h * self.stride
+
+    @property
+    def x_rows(self) -> int:
+        """Padded-input rows every strip's slab covers."""
+        return (self.n_strips - 1) * self.row_step + self.slab_h
 
 
 def strip_geometry(*, k: int, stride: int, h_out: int, w_out: int,
@@ -57,40 +118,42 @@ def strip_geometry(*, k: int, stride: int, h_out: int, w_out: int,
     """Pure strip geometry for a given strip_h (no budget accounting) —
     what the Pallas kernels and the strip-looped jnp lowering share."""
     strip_h = max(1, min(strip_h, h_out))
-    n_strips = -(-h_out // strip_h)
-    slab_h = (strip_h - 1) * stride + k
-    ms = strip_h * w_out
-    return StripPlan(
-        strip_h=strip_h, n_strips=n_strips, slab_h=slab_h,
-        row_step=strip_h * stride, ms=ms, ms_pad=-(-ms // 8) * 8,
-        x_rows=(n_strips - 1) * strip_h * stride + slab_h)
+    return StripPlan(k=k, stride=stride, w_out=w_out, strip_h=strip_h,
+                     n_strips=-(-h_out // strip_h))
 
 
-def plan_strips(*, k: int, stride: int, h_out: int, w_out: int, wp: int,
+def plan_strips(*, k: int, stride: int, h_out: int, w_out: int,
                 c_in: int, bn: int, weight_bytes: int,
                 has_shortcut: bool = False,
                 budget: int = DEFAULT_VMEM_BUDGET,
                 strip_h: int | None = None) -> StripPlan:
     """Pick output-rows-per-strip from the VMEM budget.
 
-    Cell working set = `slab_h·Wp·c_in` (int8 x slab) + ``weight_bytes``
-    (one c_out-tile of constant codes, packed or dense) + `ms_pad·bn·4`
-    for each of the int32 accumulator, the f32 y tile, and — when present
-    — the f32 shortcut tile.  Returns the largest ``strip_h ≤ h_out``
-    that fits, degenerating to one strip when the whole image fits (7×7
-    maps) and to single-row strips when even those exceed the budget.
-    ``strip_h`` overrides the search (tests / benchmarks force awkward
-    strip boundaries).
+    Cell working set, as the chip lays it out (``vmem_bytes``), with every
+    pipelined block double-buffered: the int8 x slab — one
+    ``(strip_h + halo, w_out + halo, c_in)`` block per input phase
+    (``tap_phases``) — the ``weight_bytes`` of one c_out tile of
+    constant codes (the caller's tiled single-buffer size: dense, packed
+    or depthwise), the f32 y tile and — when present — the f32 shortcut
+    tile, plus single copies of the int32 accumulator, one tap's int32
+    product and the f32 epilogue value.  Returns the largest
+    ``strip_h ≤ h_out`` that fits, degenerating to one strip when the
+    whole image fits (7×7 maps) and to single-row strips when even those
+    exceed the budget.  ``strip_h`` overrides the search (tests /
+    benchmarks force awkward strip boundaries).
     """
-    wp_c = wp * c_in
+    n_ph = len(tap_phases(k, stride))
 
     def plan_of(sh: int) -> StripPlan:
         g = strip_geometry(k=k, stride=stride, h_out=h_out, w_out=w_out,
                            strip_h=sh)
-        acc_y = g.ms_pad * bn * 4 * (3 if has_shortcut else 2)
-        return dataclasses.replace(
-            g, x_bytes=g.slab_h * wp_c,
-            cell_bytes=g.slab_h * wp_c + weight_bytes + acc_y)
+        x_b = 2 * n_ph * vmem_bytes((sh + g.halo, w_out + g.halo, c_in), 1)
+        tile = vmem_bytes((g.ms_pad, bn), 4)
+        blocks = 2 * (weight_bytes + tile * (2 if has_shortcut else 1)
+                      + 3 * vmem_bytes((1, bn), 4))   # scale, bias, amax
+        temps = 3 * tile + vmem_bytes((g.ms_pad, c_in), 1)
+        return dataclasses.replace(g, x_bytes=x_b,
+                                   cell_bytes=x_b + blocks + temps)
 
     if strip_h is not None:
         return plan_of(strip_h)

@@ -35,16 +35,29 @@ def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
     return jax.sharding.Mesh(dev, axes)
 
 
+def _devices_for(n: int, devices=None) -> list:
+    """The local device list, checked against the ``n`` devices a layout
+    asks for.  Only the CPU backend may wrap a layout round-robin onto
+    fewer devices (correctness is placement-independent, which is what
+    lets a pipeline degenerate to one CPU device in tests); on an
+    accelerator a short device list raises instead of silently stacking
+    stages or replicas onto one chip."""
+    devices = list(jax.devices()) if devices is None else list(devices)
+    if len(devices) < n and devices[0].platform != "cpu":
+        raise ValueError(
+            f"layout needs {n} {devices[0].platform} devices, "
+            f"{len(devices)} present")
+    return devices
+
+
 def pipeline_stage_devices(n_stages: int, devices=None) -> list:
     """Device list for the pipeline-parallel CNN serving path: one device
     per stage, in a 1-D 'stage' chain (the Fig 7 chip line re-expressed
-    over local accelerators).  With fewer physical devices than stages,
-    stages wrap round-robin — correctness is placement-independent (only
-    throughput changes), which is what lets the whole pipeline degenerate
-    to one CPU device in tests.  Fan a CPU host out to N devices with
-    XLA_FLAGS=--xla_force_host_platform_device_count=N.
+    over local accelerators).  With fewer CPU devices than stages, stages
+    wrap round-robin (see ``_devices_for``); fan a CPU host out to N
+    devices with XLA_FLAGS=--xla_force_host_platform_device_count=N.
     """
-    devices = list(jax.devices()) if devices is None else list(devices)
+    devices = _devices_for(n_stages, devices)
     return [devices[s % len(devices)] for s in range(n_stages)]
 
 
@@ -56,13 +69,13 @@ def replica_pipeline_devices(n_replicas: int, n_stages: int,
     local device list — replica ``r`` owns devices
     ``[r*n_stages, (r+1)*n_stages)``, so no device (and no resident
     weight byte) is shared between replicas when ``n_replicas*n_stages``
-    physical devices exist.  With fewer devices the groups wrap
-    round-robin, exactly like ``pipeline_stage_devices`` — correctness
-    is placement-independent (only throughput changes), so the whole
-    fleet degenerates to one CPU device in tests.  Fan a CPU host out
-    with XLA_FLAGS=--xla_force_host_platform_device_count=N.
+    physical devices exist.  With fewer CPU devices the groups wrap
+    round-robin, exactly like ``pipeline_stage_devices``, so the whole
+    fleet degenerates to one CPU device in tests; an accelerator with
+    too few devices raises.  Fan a CPU host out with
+    XLA_FLAGS=--xla_force_host_platform_device_count=N.
     """
     assert n_replicas >= 1 and n_stages >= 1, (n_replicas, n_stages)
-    devices = list(jax.devices()) if devices is None else list(devices)
+    devices = _devices_for(n_replicas * n_stages, devices)
     return [[devices[(r * n_stages + s) % len(devices)]
              for s in range(n_stages)] for r in range(n_replicas)]

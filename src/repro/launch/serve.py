@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 from repro import nn
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import build_cfg
 from repro.models import lm
 from repro.serving.engine import Request, ServingEngine
@@ -29,6 +30,7 @@ def main(argv=None):
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--slots", type=int, default=4)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = build_cfg(args.arch, args.preset)
     params = lm.init(jax.random.PRNGKey(0), cfg)

@@ -36,6 +36,7 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import resnet
 from repro.obs import Telemetry
 from repro.serving.faults import Fault, FaultInjector
@@ -81,6 +82,7 @@ def main(argv=None):
                          "G-channel coarse_in group (adds per-layer "
                          "zero-count outputs to the conv kernels)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     telemetry = None
     if args.trace is not None or args.sparsity_groups is not None:

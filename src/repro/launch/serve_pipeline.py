@@ -18,6 +18,7 @@ import jax
 import numpy as np
 
 from repro.core import partition
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import pipeline_stage_devices
 from repro.models import resnet
 from repro.serving.pipeline import PipelineEngine, PipelineRequest
@@ -37,6 +38,7 @@ def main(argv=None):
                     help="stage map from the Fig 7 chip packing "
                          "(re-balanced to --stages) instead of MACs")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = resnet.ResNetConfig(width_mult=args.width, num_classes=100,
                               in_hw=args.hw)

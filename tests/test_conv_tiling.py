@@ -2,6 +2,8 @@
 (tiled vs untiled, jnp and interpret lowerings, dense and bitmap-packed),
 the strip planner's budget/halo arithmetic, and the quantization-domain
 scale from the strip-reduced amax."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,7 +13,8 @@ from repro import nn
 from repro.core import compiled_linear as cl
 from repro.core.quantize import quantize_int7
 from repro.kernels import ops, ref
-from repro.kernels.tiling import plan_strips, strip_geometry
+from repro.kernels.tiling import (DEFAULT_VMEM_BUDGET, plan_strips,
+                                  strip_geometry)
 
 # the acceptance grid: k x stride x odd/even H x strip_h that does not
 # divide h_out (plus dividing ones), covering halo rows, stride-2
@@ -144,20 +147,20 @@ def test_strip_geometry_halo_math():
 def test_plan_strips_budget():
     """The planner maximizes strip_h under the budget, degenerates to one
     strip for small maps, and floors at single-row strips."""
-    small = plan_strips(k=3, stride=1, h_out=7, w_out=7, wp=9, c_in=512,
+    small = plan_strips(k=3, stride=1, h_out=7, w_out=7, c_in=512,
                         bn=128, weight_bytes=9 * 512 * 128)
     assert small.n_strips == 1                         # conv5_x fits whole
-    big = plan_strips(k=7, stride=2, h_out=112, w_out=112, wp=229, c_in=3,
+    big = plan_strips(k=7, stride=2, h_out=112, w_out=112, c_in=3,
                       bn=64, weight_bytes=7 * 7 * 3 * 64)
-    assert big.n_strips > 1 and big.cell_bytes <= 1 << 20
-    bigger = plan_strips(k=7, stride=2, h_out=112, w_out=112, wp=229,
+    assert big.n_strips > 1 and big.cell_bytes <= DEFAULT_VMEM_BUDGET
+    bigger = plan_strips(k=7, stride=2, h_out=112, w_out=112,
                          c_in=3, bn=64, weight_bytes=7 * 7 * 3 * 64,
                          budget=big.cell_bytes + (1 << 16))
     assert bigger.strip_h >= big.strip_h               # monotone in budget
-    floor = plan_strips(k=3, stride=1, h_out=64, w_out=64, wp=66, c_in=64,
+    floor = plan_strips(k=3, stride=1, h_out=64, w_out=64, c_in=64,
                         bn=128, weight_bytes=9 * 64 * 128, budget=1)
     assert floor.strip_h == 1
-    forced = plan_strips(k=3, stride=1, h_out=10, w_out=10, wp=12, c_in=8,
+    forced = plan_strips(k=3, stride=1, h_out=10, w_out=10, c_in=8,
                          bn=16, weight_bytes=9 * 8 * 16, strip_h=4)
     assert (forced.strip_h, forced.n_strips) == (4, 3)
 
@@ -167,6 +170,50 @@ def test_planner_default_untouched_for_resnet50_geoms(monkeypatch):
     strip under the default budget, so the serving path is byte-for-byte
     the pre-tiling launch (and the compiled ResNet keeps matching the
     dense path end to end, see test_conv.py)."""
-    p = plan_strips(k=3, stride=1, h_out=14, w_out=14, wp=16, c_in=64,
+    p = plan_strips(k=3, stride=1, h_out=14, w_out=14, c_in=64,
                     bn=128, weight_bytes=9 * 64 * 128)
     assert p.n_strips == 1
+
+
+# ---------------------------------------------------------------------------
+# Channel lane tiles
+# ---------------------------------------------------------------------------
+
+def test_conv_lane_tile_whole_axis():
+    """A channel axis with no 128-lane divisor is one whole-axis block
+    when its cell fits the VMEM budget, and is padded to 128-lane tiles
+    only when it does not; axes with a 128-lane divisor keep it."""
+    fits = lambda bn: plan_strips(k=1, stride=1, h_out=7, w_out=7, c_in=8,
+                                  bn=bn, weight_bytes=0)
+    too_big = lambda bn: dataclasses.replace(
+        fits(bn), cell_bytes=DEFAULT_VMEM_BUDGET + 1)
+    assert ops._conv_lane_tile(144, fits) == (144, 144)
+    assert ops._conv_lane_tile(960, fits) == (960, 960)
+    assert ops._conv_lane_tile(144, too_big) == (128, 256)
+    assert ops._conv_lane_tile(256, fits) == (128, 256)
+    assert ops._conv_lane_tile(96, too_big) == (96, 96)
+
+
+@pytest.mark.parametrize("stride", [1, 2])
+def test_whole_axis_lane_tile_bit_identical(stride, monkeypatch):
+    """144 channels (mobilenet_v2's expand width) launch as one
+    whole-axis block, unpadded: dense and depthwise kernels in interpret
+    mode equal the jnp oracles bit for bit, with and without strips."""
+    C = 144
+    x, qt = _conv_case(3, 9, 7, C=8, n_out=C)
+    key = jax.random.PRNGKey(5)
+    xd = jax.random.randint(key, (2, 9, 7, C), -127, 128, jnp.int8)
+    wd = jax.random.randint(jax.random.fold_in(key, 1), (9, C), -63, 64,
+                            jnp.int8)
+    kw = dict(x_scale=1.0, w_scale=jnp.ones((C,)), relu=False)
+    outs = {}
+    for mode in ("jnp", "interpret"):
+        monkeypatch.setenv("REPRO_PALLAS", mode)
+        for strip_h in (None, 2):
+            outs[mode, strip_h] = (
+                ops.conv2d(x, qt.values, 3, stride, strip_h=strip_h, **kw),
+                ops.conv2d_dw(xd, wd, 3, stride, strip_h=strip_h, **kw))
+    want = outs["jnp", None]
+    for got in outs.values():
+        for g_, w_ in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g_), np.asarray(w_))

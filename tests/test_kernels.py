@@ -32,7 +32,9 @@ def test_tile_pad_prime_dims_not_degenerate():
     assert _tile_pad(128, 128) == (128, 128)           # no sublane multiple
     assert _tile_pad(96, 128) == (96, 96)              # fits: single tile
     assert _tile_pad(256, 128) == (128, 256)
-    assert _tile_pad(192, 128) == (96, 192)            # clean divisor kept
+    assert _tile_pad(192, 128) == (128, 256)           # no 128-lane divisor
+    assert _tile_pad(1536, 512) == (512, 1536)         # clean divisor kept
+    assert _tile_pad(640, 512) == (128, 640)           # 128-lane divisor
     # 8*prime: the largest divisor is a sliver tile of 8 — pad instead
     assert _tile_pad(8 * 131, 128) == (128, 1152)
     assert _tile_pad(8 * 521, 512) == (512, 4608)
