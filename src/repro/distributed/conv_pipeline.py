@@ -51,6 +51,13 @@ records a span (pid ``1 + replica``, tid = stage) covering the host-side
 launch window, idle stage-ticks and edge transfers become instant
 events, and profiled stage programs' sparsity aux feeds
 ``telemetry.sparsity``.
+
+Always, telemetry or not: every ``device_put`` into a stage inlet runs
+under a ``pipe.put`` profiler span and every stage program's launch
+under ``pipe.launch`` (args ``mb``, the pipe's microbatch ordinal, and
+``stage``), each adding its host nanoseconds to the ``pipe.put_ns`` /
+``pipe.launch_ns`` counters.  The Chrome ``stage{s}`` span is stamped
+from the same ``pipe.launch`` reading, on ``time.perf_counter``.
 """
 from __future__ import annotations
 
@@ -61,6 +68,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs.metrics import MetricsRegistry
+from repro.obs.trace import host_span
 
 # every idle stage-tick gets exactly one of these (DESIGN.md §11)
 BUBBLE_CAUSES = ("fill", "starved", "drain", "host")
@@ -98,8 +106,10 @@ class ConvPipeline:
 
     ``tick(inject=None, tag=None)`` advances every stage by one
     microbatch slot and returns the ``(tag, output)`` pairs that left the
-    last stage this tick; ``serving.pipeline.PipelineEngine`` drives the
-    fill/steady/drain loop and consumes ``stats()``.
+    last stage this tick, in the order they were injected;
+    ``serving.pipeline.PipelineEngine`` drives the fill/steady/drain loop
+    and consumes ``stats()``.  ``mb_next`` is the ordinal the next
+    injected microbatch gets, which its spans carry as ``mb``.
     """
 
     def __init__(self, stages: list, replica: int = 0, metrics=None,
@@ -109,6 +119,8 @@ class ConvPipeline:
         self.n_stages = len(stages)
         self._inlet = [None] * self.n_stages    # per-stage input buffer
         self._tags = [None] * self.n_stages
+        self._mbs = [None] * self.n_stages      # their microbatch ordinals
+        self.mb_next = 0
         self.edge_bytes: list = [None] * max(self.n_stages - 1, 0)
         self.sample_inputs: list = [None] * self.n_stages
         # schedule counters live in the registry (shared with the owning
@@ -123,6 +135,8 @@ class ConvPipeline:
         self._idle = {c: [m.counter(f"pipe.stage{s}.idle.{c}")
                           for s in range(self.n_stages)]
                       for c in BUBBLE_CAUSES}
+        self._put_ns = m.counter("pipe.put_ns")
+        self._launch_ns = m.counter("pipe.launch_ns")
         # attribution state: has stage s launched since the pipe was last
         # empty?  (distinguishes fill from starved)
         self._seen = [False] * self.n_stages
@@ -173,8 +187,12 @@ class ConvPipeline:
         pid = 1 + self.replica
         if inject is not None:
             assert self._inlet[0] is None, "stage 0 inlet busy"
-            self._inlet[0] = jax.device_put(inject, self.stages[0].device)
-            self._tags[0] = tag
+            mb = self.mb_next
+            self.mb_next += 1
+            with host_span("pipe.put", self._put_ns, mb=mb, stage=0):
+                self._inlet[0] = jax.device_put(inject,
+                                                self.stages[0].device)
+            self._tags[0], self._mbs[0] = tag, mb
         # bubble attribution over the post-injection occupancy: every
         # stage-tick is either a launch or gets exactly ONE idle cause,
         # so per-cause counts sum to S·ticks − launches — the measured
@@ -203,12 +221,13 @@ class ConvPipeline:
             if self._inlet[s] is None:
                 continue
             stage = self.stages[s]
-            carry, t = self._inlet[s], self._tags[s]
+            carry, t, mb = self._inlet[s], self._tags[s], self._mbs[s]
             if self.sample_inputs[s] is None:
                 self.sample_inputs[s] = carry
             self._inlet[s] = None
-            t_begin = tr.now() if tr is not None else 0.0
-            out = stage.fn(stage.params, carry)
+            with host_span("pipe.launch", self._launch_ns, mb=mb,
+                           stage=s) as launch:
+                out = stage.fn(stage.params, carry)
             if self._profiled:
                 out, aux = out
                 tel.sparsity.add(aux, count_microbatch=(s == 0))
@@ -216,14 +235,17 @@ class ConvPipeline:
                 # the span covers the host-side launch window (JAX
                 # dispatch is async; blocking for device time here would
                 # serialize the very overlap the pipe exists for)
-                tr.span(f"stage{s}", "pipeline", pid, s, t_begin,
-                        tr.now(), tick=self._ticks.value,
-                        **self._tag_args(t))
+                tr.span(f"stage{s}", "pipeline", pid, s,
+                        launch.t0_ns * 1e-9, launch.t1_ns * 1e-9,
+                        tick=self._ticks.value, **self._tag_args(t))
             if s + 1 < self.n_stages:
                 if self.edge_bytes[s] is None:
                     self.edge_bytes[s] = carry_bytes(out)
-                out = jax.device_put(out, self.stages[s + 1].device)
+                with host_span("pipe.put", self._put_ns, mb=mb,
+                               stage=s + 1):
+                    out = jax.device_put(out, self.stages[s + 1].device)
                 self._inlet[s + 1], self._tags[s + 1] = out, t
+                self._mbs[s + 1] = mb
                 if tr is not None:
                     tr.instant("edge", "pipeline", pid, s, edge=s,
                                **self.edge_bytes[s])
@@ -260,6 +282,7 @@ class ConvPipeline:
                 tags.append(self._tags[s])
             self._inlet[s] = None
             self._tags[s] = None
+            self._mbs[s] = None
         self._seen = [False] * self.n_stages
         return tags
 

@@ -348,36 +348,39 @@ def _unit_fn(nodes, sparsity_groups):
 
         out = carry
         for n in nodes:
-            if n.op == "input":
-                out = carry
-            elif n.op == "quant":
-                out = act_quant(val(n.inputs[0]), per_row=True)
-            elif n.op == "dequant":
-                q, s = val(n.inputs[0])
-                out = q.astype(jnp.float32) * _row_scale(s)
-            elif n.op in ("conv", "dwconv"):
-                q, s = val(n.inputs[0])
-                sc = None if n.shortcut is None else val(n.shortcut)
-                w = p[n.name]
-                zc = g if (profiled and n.relu) else None
-                out = apply_conv(w["w"], q, s, gamma=w["scale"],
-                                 beta=w["bias"], shortcut=sc, relu=n.relu,
-                                 quant_out=n.quant_out, zero_count=zc)
-                if zc is not None:
-                    aux[n.name] = out[-1]
-                    out = out[0] if not n.quant_out else (out[0], out[1])
-            elif n.op == "pool":
-                out = jax.lax.reduce_window(
-                    val(n.inputs[0]), -jnp.inf, jax.lax.max,
-                    (1, n.k, n.k, 1), (1, n.stride, n.stride, 1), "SAME")
-            elif n.op == "head":
-                q, s = val(n.inputs[0])
-                pooled = jnp.mean(q.astype(jnp.float32) * _row_scale(s),
-                                  axis=(1, 2))
-                # per_row: the head's input quantization must not couple
-                # rows either, or a request's logits would depend on its
-                # microbatch neighbours
-                out = apply_linear(p[n.name]["w"], pooled, per_row=True)
+            # the node's name scopes its ops, so a trace tells the
+            # layers apart
+            with jax.named_scope(n.name):
+                if n.op == "input":
+                    out = carry
+                elif n.op == "quant":
+                    out = act_quant(val(n.inputs[0]), per_row=True)
+                elif n.op == "dequant":
+                    q, s = val(n.inputs[0])
+                    out = q.astype(jnp.float32) * _row_scale(s)
+                elif n.op in ("conv", "dwconv"):
+                    q, s = val(n.inputs[0])
+                    sc = None if n.shortcut is None else val(n.shortcut)
+                    w = p[n.name]
+                    zc = g if (profiled and n.relu) else None
+                    out = apply_conv(w["w"], q, s, gamma=w["scale"],
+                                     beta=w["bias"], shortcut=sc, relu=n.relu,
+                                     quant_out=n.quant_out, zero_count=zc)
+                    if zc is not None:
+                        aux[n.name] = out[-1]
+                        out = out[0] if not n.quant_out else (out[0], out[1])
+                elif n.op == "pool":
+                    out = jax.lax.reduce_window(
+                        val(n.inputs[0]), -jnp.inf, jax.lax.max,
+                        (1, n.k, n.k, 1), (1, n.stride, n.stride, 1), "SAME")
+                elif n.op == "head":
+                    q, s = val(n.inputs[0])
+                    pooled = jnp.mean(q.astype(jnp.float32) * _row_scale(s),
+                                      axis=(1, 2))
+                    # per_row: the head's input quantization must not couple
+                    # rows either, or a request's logits would depend on its
+                    # microbatch neighbours
+                    out = apply_linear(p[n.name]["w"], pooled, per_row=True)
             env[n.name] = out
         return (out, aux) if profiled else out
 

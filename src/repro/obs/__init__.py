@@ -3,8 +3,9 @@
 Three pillars (DESIGN.md §11):
 
 * ``trace``   — request + stage-tick span tracing, Chrome trace-event
-  export (Perfetto-loadable), schema validator.
-* ``metrics`` — Counter/Gauge/Histogram/Reservoir registry with a
+  export (Perfetto-loadable), schema validator; and the always-on
+  ``host_span`` profiler spans of the serving host step.
+* ``metrics`` — Counter/Gauge/Reservoir registry with a
   wave/life scope split; component ``stats()`` dicts are thin views
   over ``MetricsRegistry.snapshot()``.
 * ``sparsity``— post-ReLU activation zero-fraction profiling fed by the
@@ -14,18 +15,21 @@ Three pillars (DESIGN.md §11):
 (frontend → engine → pipeline → kernels).  It is **off by default**
 (``telemetry=None`` everywhere): the instrumented code guards every
 hook behind one ``is None`` check, so the off path costs a branch.
+The ``host_span`` spans and their nanosecond counters are not part of
+it: they cost a few microseconds a span and show only in a profiler
+session.
 """
 from __future__ import annotations
 
 import time
 
-from repro.obs.metrics import (Counter, Gauge, HighWater, Histogram,
-                               MetricsRegistry, Reservoir, percentile)
+from repro.obs.metrics import (Counter, Gauge, HighWater, MetricsRegistry,
+                               Reservoir, percentile)
 from repro.obs.sparsity import SparsityProfiler
 from repro.obs.trace import Trace, validate_chrome_trace
 
 __all__ = [
-    "Counter", "Gauge", "HighWater", "Histogram", "MetricsRegistry",
+    "Counter", "Gauge", "HighWater", "MetricsRegistry",
     "Reservoir", "percentile", "SparsityProfiler", "Trace",
     "validate_chrome_trace", "Telemetry",
 ]

@@ -16,13 +16,10 @@ that outlives a reset is now a bug you can test for structurally
 (``registry.wave_names()`` vs what ``snapshot()`` reports) instead of a
 list you keep in your head.
 
-Four metric kinds, all zero-dependency and O(1) per observation:
+Three metric kinds, all zero-dependency and O(1) per observation:
 
 * ``Counter``   — monotonically increasing within a wave.
 * ``Gauge``     — last-write-wins scalar; ``HighWater`` keeps the max.
-* ``Histogram`` — fixed bucket bounds, percentile by linear
-  interpolation inside the winning bucket.  Constant memory, any stream
-  length; the right tool when the window must not be bounded.
 * ``Reservoir`` — bounded sliding window of the newest N samples
   (deque), exact percentiles over the window via ``np.percentile``.
   This is the frontend's latency store: p50/p95 over the last
@@ -31,7 +28,6 @@ Four metric kinds, all zero-dependency and O(1) per observation:
 from __future__ import annotations
 
 import collections
-import math
 
 import numpy as np
 
@@ -111,68 +107,6 @@ class HighWater(Gauge):
             self.value = v
 
 
-class Histogram(_Metric):
-    """Fixed-bucket histogram: ``bounds`` are the inclusive upper edges
-    of each bucket; one implicit overflow bucket catches the rest.
-    ``percentile(q)`` interpolates linearly within the winning bucket —
-    constant memory for unbounded streams, resolution set by the bucket
-    grid (the classic prometheus trade)."""
-
-    kind = "histogram"
-
-    def __init__(self, name, bounds, scope=WAVE, help=""):
-        super().__init__(name, scope, help)
-        bounds = tuple(float(b) for b in bounds)
-        assert bounds == tuple(sorted(bounds)) and len(bounds) >= 1, bounds
-        self.bounds = bounds
-        self.counts = [0] * (len(bounds) + 1)     # + overflow
-        self.total = 0
-        self.sum = 0.0
-        self._lo = math.inf                       # for interpolation floors
-
-    def observe(self, v):
-        v = float(v)
-        self.total += 1
-        self.sum += v
-        if v < self._lo:
-            self._lo = v
-        for i, b in enumerate(self.bounds):
-            if v <= b:
-                self.counts[i] += 1
-                return
-        self.counts[-1] += 1
-
-    def reset(self):
-        self.counts = [0] * (len(self.bounds) + 1)
-        self.total = 0
-        self.sum = 0.0
-        self._lo = math.inf
-
-    def percentile(self, q):
-        """Linear interpolation inside the bucket holding the q-th
-        sample; ``None`` on empty, clamped to the last finite bound for
-        overflow hits."""
-        if self.total == 0:
-            return None
-        rank = (q / 100.0) * self.total
-        seen = 0
-        for i, c in enumerate(self.counts):
-            if c == 0:
-                continue
-            lo = self.bounds[i - 1] if i > 0 else min(self._lo, self.bounds[0])
-            hi = self.bounds[i] if i < len(self.bounds) else self.bounds[-1]
-            if seen + c >= rank:
-                frac = (rank - seen) / c
-                return float(lo + (hi - lo) * min(max(frac, 0.0), 1.0))
-            seen += c
-        return float(self.bounds[-1])
-
-    def snapshot(self):
-        return {"bounds": list(self.bounds), "counts": list(self.counts),
-                "total": self.total, "sum": self.sum,
-                "p50": self.percentile(50), "p95": self.percentile(95)}
-
-
 class Reservoir(_Metric):
     """Bounded sliding window of the newest ``window`` samples, in
     arrival order (overflow evicts the oldest).  Exact percentiles over
@@ -242,9 +176,6 @@ class MetricsRegistry:
 
     def highwater(self, name, scope=WAVE, help="", initial=0.0):
         return self._get_or_create(HighWater, name, scope, help, initial)
-
-    def histogram(self, name, bounds, scope=WAVE, help=""):
-        return self._get_or_create(Histogram, name, bounds, scope, help)
 
     def reservoir(self, name, window, scope=WAVE, help=""):
         return self._get_or_create(Reservoir, name, window, scope, help)

@@ -31,17 +31,94 @@ Design choices that keep this correct under load:
 ``python -m repro.obs.trace out.json`` validates a file against the
 schema (required keys, monotonic ts, matched B/E pairs) — CI runs this
 over the artifact it uploads.
+
+Host spans on the device trace's clock.  Independently of the Chrome
+buffer (and of ``Telemetry``), the serving host step is always
+instrumented with ``host_span``: a ``jax.profiler.TraceAnnotation``,
+so that under a profiler session every layer boundary lands in the
+xplane beside the device's operations, plus an optional registry
+``Counter`` that accumulates the block's duration in nanoseconds.  With
+no profiler session a span costs a few microseconds.  ``HOST_SPANS``
+lists every name the program emits; ``trace_gc`` adds ``host.gc`` spans
+around the interpreter's garbage collections.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
+import gc
 import json
 import sys
 import time
 
+from jax.profiler import TraceAnnotation
+
 # phase types we emit / accept
 _PH_ALLOWED = ("B", "E", "i", "I", "M", "X")
+
+# every host span the program emits, outermost layer first: the front
+# door (serving/frontend.py), the engine (serving/pipeline.py), the pipe
+# (distributed/conv_pipeline.py), and the interpreter's collections
+HOST_SPANS = (
+    "door.submit", "door.step", "door.dispatch", "door.collect",
+    "engine.step", "engine.pack", "engine.fetch", "engine.scatter",
+    "pipe.put", "pipe.launch",
+    "host.gc",
+)
+
+
+class host_span:
+    """``with host_span(name, ns_counter, **args) as sp:`` — a profiler
+    span named ``name`` (one of ``HOST_SPANS``) with ``args`` as its
+    stats; where ``ns_counter`` (a registry ``Counter``) is given, the
+    block's ``time.perf_counter_ns`` duration is added to it.  After the
+    block ``sp.t0_ns``/``sp.t1_ns`` hold its bounds on that clock, so a
+    Chrome span can be stamped from the same reading."""
+
+    __slots__ = ("_ann", "_ctr", "t0_ns", "t1_ns")
+
+    def __init__(self, name: str, ns_counter=None, **args):
+        self._ann = TraceAnnotation(name, **args)
+        self._ctr = ns_counter
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self.t0_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1_ns = time.perf_counter_ns()
+        if self._ctr is not None:
+            self._ctr.inc(self.t1_ns - self.t0_ns)
+        return self._ann.__exit__(*exc)
+
+
+class _GcSpan:
+    """A ``gc.callbacks`` hook: one ``host.gc`` span (arg ``generation``)
+    from each collection's start to its stop.  CPython runs one
+    collection at a time, so one open span is all it holds."""
+
+    def __init__(self):
+        self._open = None
+
+    def __call__(self, phase, info):
+        if phase == "start":
+            self._open = TraceAnnotation("host.gc",
+                                         generation=info["generation"])
+            self._open.__enter__()
+        elif self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+
+_GC_SPAN = _GcSpan()
+
+
+def trace_gc():
+    """Put ``host.gc`` spans around every garbage collection of the
+    process; calling it again adds nothing."""
+    if _GC_SPAN not in gc.callbacks:
+        gc.callbacks.append(_GC_SPAN)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -62,6 +62,12 @@ the kernels do, so it gets its own component.
   p50/p95 over a bounded sliding window of the most recent
   ``latency_window`` completions (an open-loop serve runs indefinitely;
   an append-forever list would leak).
+* **Host spans** — always on, telemetry or not (``repro.obs.trace``'s
+  ``host_span``): ``door.submit`` (args ``rid``, ``rows``) around
+  validation and admission, ``door.step`` around a whole step, and
+  inside it ``door.dispatch`` and ``door.collect`` around routing and
+  harvest, with the engines' own spans between them.  The front door
+  also puts a ``host.gc`` span around every garbage collection.
 
 Surface mirrors the existing engines: ``submit`` / ``step`` / ``run`` /
 ``stats`` (plus ``run_batch`` for one anonymous request).  ``run`` takes
@@ -81,6 +87,7 @@ import numpy as np
 from repro.core.compiled_linear import ensure_compiled
 from repro.launch.mesh import replica_pipeline_devices
 from repro.obs.metrics import LIFE, MetricsRegistry, percentile
+from repro.obs.trace import host_span, trace_gc
 from repro.serving.faults import ReplicaFailure
 from repro.serving.pipeline import PipelineEngine, PipelineRequest
 
@@ -128,6 +135,15 @@ class Rejected:
     reason: str = "p95-budget"
 
 
+def _row_count(images) -> int:
+    """Rows of a submitted payload, for the ``door.submit`` span's args:
+    -1 where it has no length (validation then rejects it)."""
+    try:
+        return len(images)
+    except TypeError:
+        return -1
+
+
 def _percentile(xs, q: float) -> float | None:
     """Kept as the frontend's percentile spelling; one implementation
     (``obs.metrics.percentile``) serves the whole stack."""
@@ -159,6 +175,7 @@ class ResNetFrontend:
         self.microbatch = microbatch
         self.continuous = continuous
         self.telemetry = telemetry
+        trace_gc()
         if telemetry is not None and telemetry.trace is not None:
             # spans and SLO arithmetic must share one time axis: the
             # trace's clock wins (Telemetry docstring) — callers with a
@@ -362,6 +379,11 @@ class ResNetFrontend:
         outcome: ``Admitted``, or — when ``slo_p95_s`` is set and the
         estimated wait exceeds it — ``Rejected`` (the request is NOT
         queued; ``req.rejected`` is set)."""
+        with host_span("door.submit", rid=req.rid,
+                       rows=_row_count(req.images)):
+            return self._submit(req)
+
+    def _submit(self, req):
         images = self._validate(req)
         self._check_not_live(req)
         req.images = images
@@ -614,6 +636,10 @@ class ResNetFrontend:
         completed requests.  Returns False once the whole fleet is idle.
         Raises RuntimeError when work is pending but every replica has
         failed — a dead fleet is diagnosable, not an infinite loop."""
+        with host_span("door.step"):
+            return self._step()
+
+    def _step(self) -> bool:
         self._steps_c.inc()
         t_start = self._clock()
         if not self._healthy() and (self.queue or self._requeue
@@ -624,7 +650,8 @@ class ResNetFrontend:
                 f"{self.failures} — restart_replica() to recover")
             err.fleet_stats = self.stats()
             raise err
-        self._dispatch()
+        with host_span("door.dispatch"):
+            self._dispatch()
         busy = False
         for r, eng in enumerate(self.replicas):
             if self.failed[r]:
@@ -641,7 +668,8 @@ class ResNetFrontend:
             if self.watchdog_ticks is not None:
                 self._watch(r, eng)
         self._measure_service_rate(t_start)
-        self._collect()
+        with host_span("door.collect"):
+            self._collect()
         return (busy or bool(self.queue) or bool(self._requeue)
                 or bool(self._inflight))
 

@@ -27,9 +27,21 @@ single-device reference (``reference_logits``) for ANY packing,
 ``pack_requests`` setting, stage count, or arrival order.  Each injected
 microbatch carries per-row segment tags ``(request, start_row, n_rows)``
 so completed logits scatter back to their requests.
+
+The host step is traced at its phase boundaries (``repro.obs.trace``'s
+``host_span``, always on): ``engine.step`` around a step that has work,
+inside it ``engine.pack`` (the row gather and the copy to the device),
+the pipe's ``pipe.put`` / ``pipe.launch``, ``engine.fetch`` (the wait for
+a microbatch's logits and their copy back) and ``engine.scatter`` (the
+copy into the requests).  Each phase adds its host nanoseconds to a
+wave counter of the engine's registry (``engine.step_ns``,
+``engine.pack_ns``, ``pipe.put_ns``, ``pipe.launch_ns``,
+``engine.fetch_ns``, ``engine.scatter_ns``); every span of one
+microbatch carries its ordinal as ``mb``.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 
 import jax
@@ -41,6 +53,7 @@ from repro.core.compiled_linear import ensure_compiled
 from repro.distributed.conv_pipeline import ConvPipeline, PipelineStage
 from repro.models.graph import compile_graph
 from repro.obs.metrics import LIFE, MetricsRegistry
+from repro.obs.trace import host_span
 
 
 @dataclasses.dataclass
@@ -197,6 +210,11 @@ class PipelineEngine:
         # the watchdog folds it into progress_marker
         self._rows_completed = self.metrics.counter(
             "engine.rows_completed", scope=LIFE)
+        # host nanoseconds of each phase of the step (the spans' blocks)
+        self._step_ns = self.metrics.counter("engine.step_ns")
+        self._pack_ns = self.metrics.counter("engine.pack_ns")
+        self._fetch_ns = self.metrics.counter("engine.fetch_ns")
+        self._scatter_ns = self.metrics.counter("engine.scatter_ns")
         # activation-sparsity profiling compiles DIFFERENT stage
         # programs (units return (carry, aux)); off by default
         groups = (telemetry.sparsity.groups
@@ -214,6 +232,9 @@ class PipelineEngine:
             self._build_stages(units, self.stage_block_ids, devices),
             replica=replica, metrics=self.metrics, telemetry=telemetry)
         self.queue: list[_RowSpan] = []
+        # ordinals (pipe.mb_next at injection) of the microbatches in the
+        # pipe, oldest first: the pipe completes them in that order
+        self._mbs_in_flight: collections.deque = collections.deque()
         # incremental row accounting (kept exactly in sync with the span
         # queue; _scan_pending_rows is the O(queue) oracle tests assert
         # against) — pending_rows is O(1) so the front door's routing
@@ -329,34 +350,48 @@ class PipelineEngine:
                 break                          # never cross a span boundary
         if not segs:
             return None, None
-        rows = parts[0] if len(parts) == 1 else np.concatenate(parts)
-        return segs, jnp.asarray(rows, jnp.float32)
+        n_rows = sum(n for _, _, n in segs)
+        with host_span("engine.pack", self._pack_ns, mb=self.pipe.mb_next,
+                       rows=n_rows):
+            rows = parts[0] if len(parts) == 1 else np.concatenate(parts)
+            return segs, jnp.asarray(rows, jnp.float32)
 
     def step(self) -> bool:
         """Inject one microbatch (if any rows are queued) and advance the
         schedule one tick; completed rows scatter back to their segments'
         requests.  Returns False once idle."""
-        tag = mb = None
+        if not self.queue and not self.pipe.busy:
+            return False                # idle: no span for an idle poll
+        with host_span("engine.step", self._step_ns, mb=self.pipe.mb_next):
+            return self._step()
+
+    def _step(self) -> bool:
+        tag = x = None
         if self.pipe.inlet_free:
-            tag, mb = self._next_microbatch()
-        if mb is None and not self.pipe.busy:
+            tag, x = self._next_microbatch()
+        if x is None and not self.pipe.busy:
             return False
-        if mb is not None:
-            self._rows_in_flight += int(mb.shape[0])
+        if x is not None:
+            self._rows_in_flight += int(x.shape[0])
             self._mb_injected.inc()
-            self._rows_injected.inc(int(mb.shape[0]))
+            self._rows_injected.inc(int(x.shape[0]))
+            self._mbs_in_flight.append(self.pipe.mb_next)
         self.pipe.door_rows = self.door_rows
-        for segs, out in self.pipe.tick(inject=mb, tag=tag):
-            out = np.asarray(out)
-            off = 0
-            for req, start, n in segs:
-                if req.logits is None:
-                    req.logits = np.zeros((len(req.images), out.shape[-1]),
-                                          out.dtype)
-                req.logits[start:start + n] = out[off:off + n]
-                req.rows_done += n
-                req.done = req.rows_done >= len(req.images)
-                off += n
+        for segs, out in self.pipe.tick(inject=x, tag=tag):
+            mb = self._mbs_in_flight.popleft()
+            with host_span("engine.fetch", self._fetch_ns, mb=mb):
+                out = np.asarray(out)
+            with host_span("engine.scatter", self._scatter_ns, mb=mb,
+                           rows=out.shape[0]):
+                off = 0
+                for req, start, n in segs:
+                    if req.logits is None:
+                        req.logits = np.zeros(
+                            (len(req.images), out.shape[-1]), out.dtype)
+                    req.logits[start:start + n] = out[off:off + n]
+                    req.rows_done += n
+                    req.done = req.rows_done >= len(req.images)
+                    off += n
             assert off == out.shape[0], (off, out.shape)
             self._rows_in_flight -= out.shape[0]
             self._rows_completed.inc(int(out.shape[0]))
@@ -418,6 +453,7 @@ class PipelineEngine:
                 req.rows_submitted -= n
                 spans.append((req, start, start + n))
         self._rows_in_flight = 0
+        self._mbs_in_flight.clear()
         for sp in self.queue:
             if sp.remaining:
                 spans.append((sp.req, sp.cursor, sp.stop))

@@ -15,8 +15,13 @@ The load-bearing gates (DESIGN.md §11):
   (``reference_profile``), and the profiler's reduction matches a plain
   numpy recount of synthetic aux;
 * telemetry is observation-only: logits with profiling on are
-  bit-identical to the unprofiled reference.
+  bit-identical to the unprofiled reference;
+* the always-on host spans (``obs.trace.host_span``) reach a profiler
+  trace with their args, nest inside the front door's step, and their
+  phase counters account for no more than the engine step's time.
 """
+import gc
+import glob
 import json
 import subprocess
 import sys
@@ -31,10 +36,10 @@ from repro.kernels import ops
 from repro.models import resnet
 from repro.obs import Telemetry
 from repro.obs.metrics import (LIFE, WAVE, Counter, Gauge, HighWater,
-                               Histogram, MetricsRegistry, Reservoir,
-                               percentile)
+                               MetricsRegistry, Reservoir, percentile)
 from repro.obs.sparsity import SparsityProfiler
-from repro.obs.trace import Trace, main as trace_main, validate_chrome_trace
+from repro.obs.trace import (HOST_SPANS, Trace, host_span, main as trace_main,
+                             trace_gc, validate_chrome_trace)
 from repro.serving.frontend import FrontendRequest, ResNetFrontend
 from repro.serving.loadgen import poisson_plan, run_open_loop
 from repro.serving.pipeline import reference_logits, reference_profile
@@ -80,22 +85,6 @@ def test_metric_kinds():
     for v in (3, 7, 2):
         hw.observe(v)
     assert hw.value == 7
-
-
-def test_histogram_percentiles():
-    h = Histogram("h", bounds=(1.0, 2.0, 4.0))
-    assert h.percentile(50) is None               # empty -> None
-    for v in (0.5, 1.5, 1.5, 3.0):
-        h.observe(v)
-    assert h.counts == [1, 2, 1, 0]
-    assert h.total == 4 and h.sum == pytest.approx(6.5)
-    # p50: rank 2 lands in the (1, 2] bucket; interpolated inside it
-    assert 1.0 <= h.percentile(50) <= 2.0
-    h.observe(100.0)                              # overflow bucket...
-    assert h.counts[-1] == 1
-    assert h.percentile(99) == 4.0                # ...clamps to last bound
-    snap = h.snapshot()
-    assert snap["total"] == 5 and snap["p50"] is not None
 
 
 def test_registry_scopes_and_reset_wave():
@@ -392,3 +381,141 @@ def test_frontend_snapshot_and_reset_wave_audit(monkeypatch):
     st = fe.stats()
     assert st["requests_done"] == 0 and st["latency_p50_s"] is None
     assert st["est_row_time_s"] is not None             # odometer kept
+
+
+# ---------------------------------------------------------------------------
+# host spans: profiler events on the device trace's clock + ns counters
+# ---------------------------------------------------------------------------
+
+PHASES = ("engine.pack_ns", "pipe.put_ns", "pipe.launch_ns",
+          "engine.fetch_ns", "engine.scatter_ns")
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under a profiler session; return its host-span events
+    as ``(name, start_ns, end_ns, stats)``, every one of ``HOST_SPANS``
+    on the host planes of the xplane it wrote."""
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name in HOST_SPANS]
+
+
+def test_host_span_counts_and_emits_profiler_event(tmp_path):
+    ctr = Counter("x_ns")
+
+    def work():
+        with host_span("engine.pack", ctr, mb=7, rows=3) as sp:
+            sum(range(10_000))
+        with host_span("door.step"):              # no counter: span only
+            pass
+        assert ctr.value == sp.t1_ns - sp.t0_ns > 0
+
+    evs = _profiled(tmp_path, work)
+    assert [(n, st) for n, _, _, st in evs] == [
+        ("engine.pack", {"mb": 7, "rows": 3}), ("door.step", {})]
+    (_, t0, t1, _), _ = evs
+    assert t1 - t0 >= ctr.value * 0.5             # the event spans the block
+
+
+def _fleet(n_stages=1):
+    return ResNetFrontend(CFG, _compiled(), mode="int8", n_replicas=1,
+                          n_stages=n_stages, microbatch=MB)
+
+
+def test_traced_frontend_shows_every_host_span_nested(monkeypatch,
+                                                       tmp_path):
+    monkeypatch.setenv("REPRO_PALLAS", "jnp")
+    fe = _fleet()
+    fe.run([FrontendRequest(rid=-1, images=_images(MB))])      # warmup
+
+    def serve():
+        fe.run([FrontendRequest(rid=i, images=_images(MB, seed=i))
+                for i in range(2)])
+        gc.collect()                              # one host.gc span
+
+    evs = _profiled(tmp_path, serve)
+    assert {n for n, *_ in evs} == set(HOST_SPANS)
+    steps = [(t0, t1) for n, t0, t1, _ in evs if n == "door.step"]
+    inner = [e for e in evs if e[0].split(".")[0] in ("engine", "pipe")]
+    for name, t0, t1, _ in inner:
+        assert any(s0 <= t0 and t1 <= s1 for s0, s1 in steps), name
+    # one microbatch's spans share its ordinal
+    by_mb = {}
+    for name, *_, st in inner:
+        by_mb.setdefault(st["mb"], set()).add(name)
+    assert len(by_mb) == 2
+    assert all(v == {"engine.step", "engine.pack", "pipe.put", "pipe.launch",
+                     "engine.fetch", "engine.scatter"}
+               for v in by_mb.values())
+    assert {st["rid"] for n, *_, st in evs if n == "door.submit"} == {0, 1}
+
+
+@pytest.mark.parametrize("n_stages", [1, 2])
+def test_phase_counters_within_engine_step(monkeypatch, n_stages):
+    monkeypatch.setenv("REPRO_PALLAS", "jnp")
+    fe = _fleet(n_stages)
+    fe.run([FrontendRequest(rid=i, images=_images(MB, seed=i))
+            for i in range(3)])
+    snap = fe.replicas[0].snapshot()
+    assert all(snap[k] > 0 for k in PHASES + ("engine.step_ns",))
+    assert sum(snap[k] for k in PHASES) <= snap["engine.step_ns"]
+    fe.reset_stats()                              # wave-scoped
+    assert all(fe.replicas[0].snapshot()[k] == 0 for k in PHASES)
+
+
+def test_idle_engine_step_opens_no_span(monkeypatch, tmp_path):
+    monkeypatch.setenv("REPRO_PALLAS", "jnp")
+    eng = _fleet().replicas[0]
+    evs = _profiled(tmp_path, lambda: [eng.step() for _ in range(3)])
+    assert evs == []
+    assert eng.snapshot()["engine.step_ns"] == 0
+
+
+def test_gc_span_is_registered_once(monkeypatch):
+    monkeypatch.setenv("REPRO_PALLAS", "jnp")
+    _fleet()
+    _fleet()
+    trace_gc()
+    hooks = [cb for cb in gc.callbacks if type(cb).__name__ == "_GcSpan"]
+    assert len(hooks) == 1
+
+
+def test_chrome_stage_spans_are_the_launch_spans(monkeypatch):
+    """The Chrome export still validates with its ``stage{s}`` spans, and
+    they are stamped from the ``pipe.launch`` blocks: their durations sum
+    to ``pipe.launch_ns``."""
+    monkeypatch.setenv("REPRO_PALLAS", "jnp")
+    tel = Telemetry(trace=True)
+    fe = ResNetFrontend(CFG, _compiled(), mode="int8", n_replicas=1,
+                        n_stages=2, microbatch=MB, telemetry=tel)
+    fe.run([FrontendRequest(rid=i, images=_images(MB, seed=i))
+            for i in range(3)])
+    obj = tel.trace.to_chrome_trace()
+    assert validate_chrome_trace(obj) == []
+    stages = [s for s in tel.trace.spans if s.cat == "pipeline"]
+    assert {s.name for s in stages} == {"stage0", "stage1"}
+    assert len(stages) == 2 * 3
+    launch_us = fe.replicas[0].snapshot()["pipe.launch_ns"] / 1e3
+    assert sum(s.dur for s in stages) == pytest.approx(launch_us, rel=1e-6)
+
+
+def test_graph_nodes_scope_their_ops():
+    """Each graph node's ops carry the node's name in their op_name, so a
+    device trace can tell the layers apart."""
+    from repro.models.graph import compile_graph
+    units = compile_graph(CFG.graph(), _compiled())
+    u = units[1]
+    x = jax.ShapeDtypeStruct((MB,) + CFG.graph().in_shape(), np.float32)
+    carry = jax.eval_shape(units[0].fn, units[0].params, x)
+    text = jax.jit(u.fn).lower(u.params, carry).as_text(debug_info=True)
+    names = [n.name for n in CFG.graph().nodes if n.name in u.params]
+    assert names and all(f"/{n}/" in text for n in names), names
