@@ -38,6 +38,17 @@ wave counter of the engine's registry (``engine.step_ns``,
 ``engine.pack_ns``, ``pipe.put_ns``, ``pipe.launch_ns``,
 ``engine.fetch_ns``, ``engine.scatter_ns``); every span of one
 microbatch carries its ordinal as ``mb``.
+
+Deferred fetch: the step launches microbatch n+1 before it blocks on
+microbatch n's logits.  A completed microbatch's output stays an
+unfetched device array until the next step has launched its successor,
+whenever more work is known to be waiting (rows in the engine's queue,
+or ``door_rows`` held at the front door); otherwise the step fetches it
+at once.  The device then always has the next program, and its input
+copy, queued behind the one it runs, while the host waits, validates
+the next request and packs.  At most one microbatch stays unfetched
+between steps; ``engine.mb_overlapped`` counts the microbatches
+injected while one was.
 """
 from __future__ import annotations
 
@@ -215,6 +226,9 @@ class PipelineEngine:
         self._pack_ns = self.metrics.counter("engine.pack_ns")
         self._fetch_ns = self.metrics.counter("engine.fetch_ns")
         self._scatter_ns = self.metrics.counter("engine.scatter_ns")
+        # microbatches injected while an earlier one's logits were still
+        # unfetched: how often the deferred fetch engages
+        self._mb_overlapped = self.metrics.counter("engine.mb_overlapped")
         # activation-sparsity profiling compiles DIFFERENT stage
         # programs (units return (carry, aux)); off by default
         groups = (telemetry.sparsity.groups
@@ -235,6 +249,9 @@ class PipelineEngine:
         # ordinals (pipe.mb_next at injection) of the microbatches in the
         # pipe, oldest first: the pipe completes them in that order
         self._mbs_in_flight: collections.deque = collections.deque()
+        # completed microbatches whose logits are still on the device,
+        # oldest first, as (mb, segments, device output)
+        self._unfetched: collections.deque = collections.deque()
         # incremental row accounting (kept exactly in sync with the span
         # queue; _scan_pending_rows is the O(queue) oracle tests assert
         # against) — pending_rows is O(1) so the front door's routing
@@ -359,8 +376,9 @@ class PipelineEngine:
     def step(self) -> bool:
         """Inject one microbatch (if any rows are queued) and advance the
         schedule one tick; completed rows scatter back to their segments'
-        requests.  Returns False once idle."""
-        if not self.queue and not self.pipe.busy:
+        requests, the newest possibly one step later (deferred fetch).
+        Returns False once idle."""
+        if not self.queue and not self.pipe.busy and not self._unfetched:
             return False                # idle: no span for an idle poll
         with host_span("engine.step", self._step_ns, mb=self.pipe.mb_next):
             return self._step()
@@ -369,33 +387,49 @@ class PipelineEngine:
         tag = x = None
         if self.pipe.inlet_free:
             tag, x = self._next_microbatch()
-        if x is None and not self.pipe.busy:
+        if x is None and not self.pipe.busy and not self._unfetched:
             return False
         if x is not None:
             self._rows_in_flight += int(x.shape[0])
             self._mb_injected.inc()
             self._rows_injected.inc(int(x.shape[0]))
+            if self._unfetched:
+                self._mb_overlapped.inc()
             self._mbs_in_flight.append(self.pipe.mb_next)
-        self.pipe.door_rows = self.door_rows
-        for segs, out in self.pipe.tick(inject=x, tag=tag):
-            mb = self._mbs_in_flight.popleft()
-            with host_span("engine.fetch", self._fetch_ns, mb=mb):
-                out = np.asarray(out)
-            with host_span("engine.scatter", self._scatter_ns, mb=mb,
-                           rows=out.shape[0]):
-                off = 0
-                for req, start, n in segs:
-                    if req.logits is None:
-                        req.logits = np.zeros(
-                            (len(req.images), out.shape[-1]), out.dtype)
-                    req.logits[start:start + n] = out[off:off + n]
-                    req.rows_done += n
-                    req.done = req.rows_done >= len(req.images)
-                    off += n
-            assert off == out.shape[0], (off, out.shape)
-            self._rows_in_flight -= out.shape[0]
-            self._rows_completed.inc(int(out.shape[0]))
+        done = []
+        if x is not None or self.pipe.busy:
+            self.pipe.door_rows = self.door_rows
+            done = self.pipe.tick(inject=x, tag=tag)
+        for segs, out in done:
+            self._unfetched.append((self._mbs_in_flight.popleft(), segs, out))
+        # the newest output waits for the next step's launch only if it
+        # left the pipe in this tick and more work is waiting; anything
+        # older is fetched now, so no output waits more than one step
+        keep = int(bool(done) and (self._queued_rows > 0
+                                   or self.door_rows > 0))
+        while len(self._unfetched) > keep:
+            self._fetch(*self._unfetched.popleft())
         return True
+
+    def _fetch(self, mb, segs, out):
+        """Wait for one microbatch's logits, copy them back, and scatter
+        them into their requests."""
+        with host_span("engine.fetch", self._fetch_ns, mb=mb):
+            out = np.asarray(out)
+        with host_span("engine.scatter", self._scatter_ns, mb=mb,
+                       rows=out.shape[0]):
+            off = 0
+            for req, start, n in segs:
+                if req.logits is None:
+                    req.logits = np.zeros(
+                        (len(req.images), out.shape[-1]), out.dtype)
+                req.logits[start:start + n] = out[off:off + n]
+                req.rows_done += n
+                req.done = req.rows_done >= len(req.images)
+                off += n
+        assert off == out.shape[0], (off, out.shape)
+        self._rows_in_flight -= out.shape[0]
+        self._rows_completed.inc(int(out.shape[0]))
 
     def run(self, requests: list) -> list:
         for r in requests:
@@ -407,10 +441,10 @@ class PipelineEngine:
     @property
     def pending_rows(self) -> int:
         """Rows accepted but not yet delivered: the queue's unsubmitted
-        rows plus the exact rows still rotating through the stages
-        (partial microbatches count their real size) — the load metric
-        ``serving.frontend.ResNetFrontend``'s least-loaded router
-        compares across replicas.  O(1): incrementally maintained (the
+        rows plus the exact rows still rotating through the stages or
+        waiting unfetched (partial microbatches count their real size)
+        — the load metric ``serving.frontend.ResNetFrontend``'s
+        least-loaded router compares across replicas.  O(1): incrementally maintained (the
         router reads it once per admitted row chunk, so a linear scan
         here made dispatch O(requests²) under load — tests assert it
         equals ``_scan_pending_rows``)."""
@@ -437,23 +471,26 @@ class PipelineEngine:
     def extract_pending(self) -> list:
         """Cancel everything this engine still owes and return it as
         ``(request, start, stop)`` row spans — the drain half of replica
-        failure recovery.  Covers both the un-injected queue spans and
-        the rows buffered in stage inlets (via
-        ``ConvPipeline.cancel_in_flight``; their ``rows_submitted`` is
-        rewound so re-execution accounting starts clean).  Rows that
-        already scattered back to their requests are NOT extracted —
-        they were delivered by the same program every replica runs, and
-        per-row quantization domains make the re-executed remainder
-        bit-identical to the never-failed reference (DESIGN.md §9/§10).
-        Leaves the engine idle: empty queue, empty inlets, zeroed
-        pending-row accounting."""
+        failure recovery.  Covers the un-injected queue spans, the rows
+        of unfetched microbatches, and the rows buffered in stage inlets
+        (via ``ConvPipeline.cancel_in_flight``); the last two have their
+        ``rows_submitted`` rewound so re-execution accounting starts
+        clean.  Rows that already scattered back to their requests are
+        NOT extracted — they were delivered by the same program every
+        replica runs, and per-row quantization domains make the
+        re-executed remainder bit-identical to the never-failed
+        reference (DESIGN.md §9/§10).
+        Leaves the engine idle: empty queue, empty inlets, nothing
+        unfetched, zeroed pending-row accounting."""
         spans = []
-        for segs in self.pipe.cancel_in_flight():
+        owed = [segs for _, segs, _ in self._unfetched]
+        for segs in owed + self.pipe.cancel_in_flight():
             for req, start, n in segs:
                 req.rows_submitted -= n
                 spans.append((req, start, start + n))
         self._rows_in_flight = 0
         self._mbs_in_flight.clear()
+        self._unfetched.clear()
         for sp in self.queue:
             if sp.remaining:
                 spans.append((sp.req, sp.cursor, sp.stop))
@@ -473,9 +510,10 @@ class PipelineEngine:
 
     def reset_counters(self):
         """Zero the wave-scoped schedule + occupancy counters (idle only
-        — delegates the busy check to ConvPipeline.reset_counters); the
-        lifetime ``rows_completed`` odometer is LIFE-scoped and
-        survives."""
+        — nothing unfetched, and the pipe's own busy check in
+        ConvPipeline.reset_counters); the lifetime ``rows_completed``
+        odometer is LIFE-scoped and survives."""
+        assert not self._unfetched, "reset_counters with logits unfetched"
         self.pipe.reset_counters()
         self.metrics.reset_wave()
 

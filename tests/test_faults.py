@@ -30,7 +30,8 @@ from repro.serving.frontend import (Admitted, FrontendRequest, Rejected,
                                     ResNetFrontend)
 from repro.serving.loadgen import (offered_rows_per_s, poisson_plan,
                                    run_open_loop)
-from repro.serving.pipeline import reference_logits
+from repro.serving.pipeline import (PipelineEngine, PipelineRequest,
+                                    reference_logits)
 
 CFG = resnet.ResNetConfig(width_mult=0.125, num_classes=4, in_hw=8)
 MB = 2
@@ -303,6 +304,61 @@ def test_watchdog_no_false_positive_at_threshold_one(monkeypatch):
     assert fe.stats()["replicas_failed"] == 0, fe.stats()["failures"]
     for r in reqs:
         assert r.done
+
+
+def test_extract_pending_returns_unfetched_rows(monkeypatch):
+    """Rows whose logits are computed but still unfetched are owed: the
+    engine returns them with ``rows_submitted`` rewound, and a fleet that
+    kills a replica in that state still answers every request
+    bit-identically."""
+    monkeypatch.setenv("REPRO_PALLAS", "jnp")
+    eng = PipelineEngine(CFG, _compiled(), mode="int8", n_stages=1,
+                         microbatch=MB)
+    req = PipelineRequest(rid=7, images=_images(MB))
+    eng.submit(req)
+    eng.door_rows = 3                       # work waits: the fetch defers
+    assert eng.step() and len(eng._unfetched) == 1
+    assert eng.extract_pending() == [(req, 0, MB)]
+    assert req.rows_submitted == 0 and not req.done
+    assert eng.pending_rows == 0 and not eng._unfetched
+    assert eng.step() is False
+
+    fe = _fleet(True, 1)
+    fe.run(_wave(0))                               # warm
+    reqs = _wave(100, n_reqs=8)
+    for r in reqs:
+        fe.submit(r)
+    fe.step()
+    assert fe.replicas[0]._unfetched               # logits left on device
+    inj = FaultInjector()
+    inj.arm(fe.replicas[0], Fault("kill", at_step=0))
+    while fe.step():
+        pass
+    _check_refs(reqs)
+    _assert_drained(fe)
+    st = fe.stats()
+    assert st["replicas_failed"] == 1 and st["rows_requeued"] >= 2 * MB, st
+
+
+@pytest.mark.parametrize("admit_rows", (None, MB))
+def test_watchdog_quiet_with_unfetched_microbatch(monkeypatch, admit_rows):
+    """A healthy replica that holds an unfetched microbatch between steps
+    still changes its progress marker on every step, so even
+    watchdog_ticks=1 never fails it; with ``admit_rows`` at one
+    microbatch the door cannot hand it more, and its next step only
+    fetches."""
+    monkeypatch.setenv("REPRO_PALLAS", "jnp")
+    fe = _fleet(True, 1, watchdog_ticks=1, admit_rows=admit_rows)
+    reqs = _wave(0, n_reqs=12)
+    for r in reqs:
+        fe.submit(r)
+    held = 0
+    while fe.step():
+        held += sum(bool(eng._unfetched) for eng in fe.replicas)
+    assert held >= 3
+    assert fe.stats()["replicas_failed"] == 0, fe.stats()["failures"]
+    _check_refs(reqs)
+    _assert_drained(fe)
 
 
 def test_door_rows_counter_matches_scan_through_failure(monkeypatch):

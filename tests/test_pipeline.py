@@ -229,6 +229,106 @@ def test_explicit_stage_map_and_partition_plan(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# Deferred fetch: microbatch n+1 launches before n's logits are fetched
+# ---------------------------------------------------------------------------
+
+def _spy_order(eng):
+    """Record the engine's injections and fetches, in order, as
+    ``("inject", mb)`` / ``("fetch", mb)``."""
+    events = []
+    tick, fetch = eng.pipe.tick, eng._fetch
+
+    def spy_tick(inject=None, tag=None):
+        if inject is not None:
+            events.append(("inject", eng.pipe.mb_next))
+        return tick(inject=inject, tag=tag)
+
+    def spy_fetch(mb, segs, out):
+        events.append(("fetch", mb))
+        return fetch(mb, segs, out)
+
+    eng.pipe.tick, eng._fetch = spy_tick, spy_fetch
+    return events
+
+
+@pytest.mark.parametrize("door_rows", (0, 5))
+@pytest.mark.parametrize("n_stages", (1, 2))
+def test_deferred_fetch_launches_next_first(monkeypatch, n_stages,
+                                            door_rows):
+    """With microbatches queued, each step launches microbatch n+1 before
+    it blocks on n's logits, with or without rows held at the front
+    door; every request stays bit-identical to ``reference_logits``."""
+    monkeypatch.setenv("REPRO_PALLAS", "jnp")
+    eng = PipelineEngine(CFG, _compiled("int8"), mode="int8",
+                         n_stages=n_stages, microbatch=2)
+    x = _images(6)
+    reqs = [PipelineRequest(rid=1, images=x[:3]),
+            PipelineRequest(rid=2, images=x[3:])]
+    for r in reqs:
+        eng.submit(r)
+    events = _spy_order(eng)
+    eng.door_rows = door_rows
+    while eng.step():
+        assert eng.pending_rows == eng._scan_pending_rows()
+    assert eng.pending_rows == 0 and not eng._unfetched
+    want = _reference("int8", "jnp", 6, 2)
+    np.testing.assert_array_equal(reqs[0].logits, want[:3])
+    np.testing.assert_array_equal(reqs[1].logits, want[3:])
+    assert [e for e in events if e[0] == "fetch"] == [
+        ("fetch", m) for m in range(3)]
+    for m in range(2):
+        assert events.index(("inject", m + 1)) < events.index(("fetch", m))
+    # the first n_stages injections find nothing completed yet
+    snap = eng.snapshot()
+    assert snap["engine.mb_injected"] == 3
+    assert snap["engine.mb_overlapped"] == 3 - n_stages
+
+
+def test_no_waiting_work_fetches_at_once(monkeypatch):
+    """The server's path: with the queue empty and no rows at the door,
+    a step fetches its own microbatch before it returns."""
+    monkeypatch.setenv("REPRO_PALLAS", "jnp")
+    eng = PipelineEngine(CFG, _compiled("int8"), mode="int8", n_stages=1,
+                         microbatch=2)
+    events = _spy_order(eng)
+    req = PipelineRequest(rid=1, images=_images(2))
+    eng.submit(req)
+    assert eng.step() is True
+    assert events == [("inject", 0), ("fetch", 0)]
+    assert req.done and not eng._unfetched and eng.pending_rows == 0
+    assert eng.snapshot()["engine.mb_overlapped"] == 0
+    assert eng.step() is False
+    np.testing.assert_array_equal(req.logits, _reference("int8", "jnp", 2,
+                                                         2))
+
+
+def test_unfetched_output_keeps_engine_busy_until_drained(monkeypatch):
+    """An engine holding unfetched logits is not idle: ``step()`` returns
+    True, its rows stay pending, ``reset_counters`` refuses, and the
+    next step fetches them even though the door still reports rows."""
+    monkeypatch.setenv("REPRO_PALLAS", "jnp")
+    eng = PipelineEngine(CFG, _compiled("int8"), mode="int8", n_stages=1,
+                         microbatch=2)
+    req = PipelineRequest(rid=1, images=_images(2))
+    eng.submit(req)
+    eng.door_rows = 5                       # rows the door holds elsewhere
+    assert eng.step() is True
+    assert len(eng._unfetched) == 1 and not req.done
+    assert not eng.queue and not eng.pipe.busy
+    assert eng.pending_rows == eng._scan_pending_rows() == 2
+    with pytest.raises(AssertionError, match="unfetched"):
+        eng.reset_counters()
+    ticks = eng.pipe.ticks
+    assert eng.step() is True               # fetches; the pipe does not tick
+    assert eng.pipe.ticks == ticks
+    assert req.done and not eng._unfetched and eng.pending_rows == 0
+    assert eng.step() is False
+    eng.reset_counters()
+    np.testing.assert_array_equal(req.logits, _reference("int8", "jnp", 2,
+                                                         2))
+
+
+# ---------------------------------------------------------------------------
 # Persistent per-stage weights: disjoint param subtrees (spy)
 # ---------------------------------------------------------------------------
 
